@@ -160,8 +160,9 @@ def stage_kernels(cfg: ModelConfig, donate: bool) -> StageKernels:
         (dhp,) = vjp(g)
         return dhp
 
-    embed_bwd = jax.jit(embed_bwd_impl,
-                        donate_argnums=(2,) if donate else ())
+    # no donation: no output has the cotangent's shape, so XLA could
+    # not reuse its buffer and would only warn
+    embed_bwd = jax.jit(embed_bwd_impl)
 
     def head_impl(head_p, hidden, labels):
         """hidden: (B, mb, S, D); labels: (B, mb, S).

@@ -1,13 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a real TPU runtime the kernels compile natively; on the CPU container
-they run in interpret mode (``REPRO_KERNEL_INTERPRET=1``, the default when
-no TPU is present) so correctness is testable everywhere.
+The kernels compile natively for the TPU.  ``interpret=True`` runs the
+same kernel body through the Pallas interpreter instead, which is how
+tests check them on the CPU; it is only ever the caller's choice.
+Without it, a backend other than the TPU refuses the kernel.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,17 +16,11 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.ssd_scan import ssd_scan_bhsp
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env == "1"
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k"))
+                                             "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int = 128, block_k: int = 128,
+                    interpret: bool = False):
     """q: (B, S, H, hd); k, v: (B, S, KH, hd) — GQA handled here.
 
     Returns (B, S, H, hd).
@@ -42,12 +36,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
     out = flash_attention_bhsd(qf, kf, vf, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
-                               interpret=_interpret())
+                               interpret=interpret)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = False):
     """Model-layout SSD: x (B, S, H, P), dt (B, S, H), Bm/Cm (B, S, N).
 
     Returns (y (B, S, H, P), h_final (B, H, P, N)).
@@ -55,5 +49,5 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128):
     xk = x.transpose(0, 2, 1, 3)
     dtk = dt.transpose(0, 2, 1)
     y, hf = ssd_scan_bhsp(xk, dtk, A, Bm, Cm, chunk=chunk,
-                          interpret=_interpret())
+                          interpret=interpret)
     return y.transpose(0, 2, 1, 3), hf

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, h_scr, *,
                 chunk: int):
@@ -43,26 +40,31 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, h_scr, *,
     Bm = b_ref[...].astype(jnp.float32)           # (chunk, N)
     Cm = c_ref[...].astype(jnp.float32)           # (chunk, N)
 
-    loga = dt[:, 0] * A                           # (chunk,)
-    Lc = jnp.cumsum(loga)                         # inclusive
+    loga = dt * A                                 # (chunk, 1)
     idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jdx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     causal = idx >= jdx
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # ones matmul, at full f32 precision because exp() amplifies its error
+    Lc = jnp.dot(causal.astype(jnp.float32), loga,
+                 precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)      # (chunk, 1)
+    L_total = jnp.sum(loga)                       # == Lc[-1]
 
     CB = jnp.dot(Cm, Bm.T, preferred_element_type=jnp.float32)
-    delta = Lc[:, None] - Lc[None, :]
+    delta = Lc - jnp.broadcast_to(Lc, (chunk, chunk)).T
     delta = jnp.where(causal, delta, 0.0)         # mask exponent pre-exp
-    M = CB * jnp.exp(delta) * dt[:, 0][None, :]
+    M = CB * jnp.exp(delta) * jnp.broadcast_to(dt, (chunk, chunk)).T
     M = jnp.where(causal, M, 0.0)
     y_intra = jnp.dot(M, x, preferred_element_type=jnp.float32)
 
     h = h_scr[...]                                # (P, N)
     y_state = jnp.dot(Cm, h.T,
-                      preferred_element_type=jnp.float32) * jnp.exp(Lc)[:, None]
+                      preferred_element_type=jnp.float32) * jnp.exp(Lc)
 
-    w = jnp.exp(Lc[-1] - Lc) * dt[:, 0]           # (chunk,)
-    h_new = jnp.exp(Lc[-1]) * h + jnp.dot(
-        (x * w[:, None]).T, Bm, preferred_element_type=jnp.float32)
+    w = jnp.exp(L_total - Lc) * dt                # (chunk, 1)
+    h_new = jnp.exp(L_total) * h + jnp.dot(
+        (x * w).T, Bm, preferred_element_type=jnp.float32)
     h_scr[...] = h_new
 
     y_ref[...] = (y_intra + y_state).astype(y_ref.dtype)
@@ -104,7 +106,7 @@ def ssd_scan_bhsp(x, dt, A, Bm, Cm, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt.reshape(B, H, S, 1), A.reshape(H, 1, 1), Bm, Cm)

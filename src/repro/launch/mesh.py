@@ -9,18 +9,27 @@ Multi-pod  : (2, 16, 16) axes (pod, data, model) = 512 chips
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    # Auto axes: the model code places tensors with with_sharding_constraint,
+    # which refuses the Explicit axes jax.make_mesh defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Whatever devices exist locally (tests / smoke runs)."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+def make_host_mesh(devices=None):
+    """A (1, n) ("data", "model") mesh over ``devices`` (default: every
+    local device) — tests, smoke runs, one host."""
+    devices = list(jax.devices() if devices is None else devices)
+    return _auto_mesh((1, len(devices)), ("data", "model"), devices)
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

@@ -18,8 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")

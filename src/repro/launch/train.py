@@ -18,21 +18,25 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 
 import numpy as np
 
 
-def run_spmd(args):
+def make_spmd(args, mesh):
+    """Whole-model SPMD training state on ``mesh``.
+
+    Returns ``(cfg, step_fn, params, opt_state, shard)``: params and AdamW
+    state are created already placed by the sharding rules (each device
+    holds its share, not a replica), and ``step_fn`` is the jitted step
+    over those placements, donating the state it replaces.
+    """
     import jax
     import jax.numpy as jnp
 
-    from repro.checkpoint import store
     from repro.configs import get_config
     from repro.data.pipeline import DataConfig, DataNodeShard
-    from repro.launch.mesh import make_host_mesh
-    from repro.launch.steps import make_train_step
+    from repro.launch.steps import make_train_step, train_shardings
     from repro.models.transformer import init_params
     from repro.optim.adamw import AdamW
     from repro.parallel.sharding import ShardingRules
@@ -40,27 +44,39 @@ def run_spmd(args):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(num_layers=args.layers, d_model=args.d_model)
-    mesh = make_host_mesh()
     rules = ShardingRules()
     opt = AdamW(lr=args.lr)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    opt_state = opt.init(params)
-    step_fn = jax.jit(make_train_step(cfg, opt, mesh=mesh, rules=rules))
-
+    key = jax.random.PRNGKey(args.seed)
+    init = lambda k: init_params(cfg, k)
+    params_abs = jax.eval_shape(init, key)
+    opt_abs = jax.eval_shape(opt.init, params_abs)
+    tok = jax.ShapeDtypeStruct((args.batch, args.seq_len), jnp.int32)
+    (pspec, ospec, bspec), out_spec = train_shardings(
+        cfg, params_abs, opt_abs, {"tokens": tok, "labels": tok}, rules,
+        mesh)
+    params = jax.jit(init, out_shardings=pspec)(key)
+    opt_state = jax.jit(opt.init, out_shardings=ospec)(params)
+    step_fn = jax.jit(make_train_step(cfg, opt, mesh=mesh, rules=rules),
+                      in_shardings=(pspec, ospec, bspec),
+                      out_shardings=out_spec, donate_argnums=(0, 1))
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                     batch_size=args.batch, microbatch_size=args.batch,
                     seed=args.seed)
-    shard = DataNodeShard(dc, 0, 1)
-    with mesh:
-        for step in range(args.steps):
-            b = shard.next_batch()
-            batch = {"tokens": jnp.asarray(b["tokens"]),
-                     "labels": jnp.asarray(b["labels"])}
-            t0 = time.time()
-            params, opt_state, loss = step_fn(params, opt_state, batch)
-            if step % args.log_every == 0:
-                print(f"step {step:4d} loss {float(loss):.4f} "
-                      f"({time.time()-t0:.2f}s)")
+    return cfg, step_fn, params, opt_state, DataNodeShard(dc, 0, 1)
+
+
+def run_spmd(args):
+    from repro.checkpoint import store
+    from repro.launch.mesh import make_host_mesh
+
+    _, step_fn, params, opt_state, shard = make_spmd(args, make_host_mesh())
+    for step in range(args.steps):
+        t0 = time.time()
+        params, opt_state, loss = step_fn(params, opt_state,
+                                          shard.next_batch())
+        if step % args.log_every == 0:
+            print(f"step {step:4d} loss {float(loss):.4f} "
+                  f"({time.time()-t0:.2f}s)")
     if args.checkpoint:
         store.save(args.checkpoint, params, step=args.steps)
         print("checkpoint ->", args.checkpoint)
@@ -68,7 +84,12 @@ def run_spmd(args):
     return float(loss)
 
 
-def run_gwtf(args):
+def make_gwtf(args):
+    """The paper's decentralized trainer and its data-node shards.
+
+    Returns ``(cfg, trainer, shards)``; ``shards`` maps each data node's
+    id to its :class:`~repro.data.pipeline.DataNodeShard`.
+    """
     from repro.configs import get_config
     from repro.core.executor import DecentralizedTrainer
     from repro.core.flow.graph import geo_distributed_network
@@ -91,6 +112,11 @@ def run_gwtf(args):
                    batch_size=args.microbatches * args.batch,
                    microbatch_size=args.batch, seed=args.seed + d.id),
         d.id, args.data_nodes) for d in net.data_nodes()}
+    return cfg, trainer, shards
+
+
+def run_gwtf(args):
+    _, trainer, shards = make_gwtf(args)
     for it in range(args.iterations):
         batches = {dn: shards[dn].microbatches() for dn in shards}
         r = trainer.iteration(batches)
@@ -100,7 +126,7 @@ def run_gwtf(args):
     return trainer.losses[-1]
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gwtf-llama-300m")
     ap.add_argument("--mode", choices=("spmd", "gwtf"), default="gwtf")
@@ -121,7 +147,14 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--checkpoint", default=None)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    args = build_parser().parse_args()
     if args.mode == "spmd":
         run_spmd(args)
     else:

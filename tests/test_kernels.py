@@ -73,7 +73,8 @@ def test_flash_attention_gqa_wrapper():
     q = jax.random.normal(k1, (B, S, H, D))
     k = jax.random.normal(k2, (B, S, KH, D))
     v = jax.random.normal(k3, (B, S, KH, D))
-    out = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    out = ops.flash_attention(q, k, v, block_q=64, block_k=64,
+                              interpret=True)
     kr = jnp.repeat(k, H // KH, axis=2)
     vr = jnp.repeat(v, H // KH, axis=2)
     ref = attention_reference(
@@ -83,6 +84,17 @@ def test_flash_attention_gqa_wrapper():
     ref = ref.reshape(B, H, S, D).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_never_infer_interpret_mode():
+    """Interpret mode is never inferred: off the TPU, a kernel call
+    that does not ask for it raises instead of silently interpreting."""
+    x = jnp.ones((1, 128, 2, 64))
+    if jax.default_backend() == "tpu":
+        assert ops.flash_attention(x, x, x).shape == x.shape
+        return
+    with pytest.raises(ValueError, match="interpret"):
+        ops.flash_attention(x, x, x, block_q=64, block_k=64)
 
 
 # ---------------------------------------------------------------------------

@@ -146,11 +146,19 @@ class TestConfigReduction:
 
 class TestKernelIntegration:
     """The use_kernel=True path routes model attention through the Pallas
-    flash kernel (interpret mode on CPU) — must match the jnp path."""
+    flash kernel — must match the jnp path."""
 
-    def test_forward_with_kernel_matches(self):
+    def test_forward_with_kernel_matches(self, monkeypatch):
+        import functools
+
         import numpy as np
+        from repro.kernels import ops
         from repro.models.transformer import forward_hidden, init_params
+        if jax.default_backend() != "tpu":
+            # the model never asks for interpret mode; off the TPU the
+            # test asks for it on the model's behalf
+            monkeypatch.setattr(ops, "flash_attention", functools.partial(
+                ops.flash_attention, interpret=True))
         cfg = get_config("tinyllama-1.1b").reduced(num_layers=2,
                                                    d_model=128)
         key = jax.random.PRNGKey(0)

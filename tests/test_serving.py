@@ -80,7 +80,17 @@ def test_serving_inputs_seeded_determinism():
     assert not bool(jnp.array_equal(a[1], c[1]))
 
 
-def test_serve_driver_seeded_determinism(monkeypatch, capsys):
+@pytest.fixture()
+def no_compile_cache(monkeypatch):
+    """The CLI turns on the persistent compile cache for its process; a
+    test process keeps its compiles to itself."""
+    import repro.launch.serve as serve
+
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+
+
+def test_serve_driver_seeded_determinism(monkeypatch, capsys,
+                                        no_compile_cache):
     """Two driver runs with the same ``--seed`` emit identical sampled
     tokens; a different seed diverges (the pre-fix driver fed the same
     key to init and to every sampling step)."""
@@ -105,7 +115,7 @@ def test_serve_driver_seeded_determinism(monkeypatch, capsys):
 # Absorbed smoke coverage (formerly tests/test_serve_smoke.py)
 # ---------------------------------------------------------------------------
 
-def test_serve_driver_tiny_decode(monkeypatch, capsys):
+def test_serve_driver_tiny_decode(monkeypatch, capsys, no_compile_cache):
     """Run the real `repro.launch.serve` CLI end to end on a reduced
     config: prefill + 2 greedy decode steps."""
     import repro.launch.serve as serve
@@ -120,7 +130,7 @@ def test_serve_driver_tiny_decode(monkeypatch, capsys):
     assert "decoded 2 steps" in out
 
 
-def test_serve_driver_long_mode(monkeypatch, capsys):
+def test_serve_driver_long_mode(monkeypatch, capsys, no_compile_cache):
     """The sliding-window ring-buffer path (--long) decodes past the
     window without growing the cache."""
     import repro.launch.serve as serve

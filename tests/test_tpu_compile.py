@@ -1,0 +1,116 @@
+"""Compiles for a described TPU v5e chip, no chip attached.
+
+The TPU compiler is installed with jaxlib, so it refuses here what the
+chip would refuse: Mosaic lowerings interpret mode never checks, and
+programs that do not fit the chip's memory.  Kernels are compiled at the
+shapes their callers use; the stage programs at the full width of
+``gwtf-llama-300m`` on the chunk the staged trainer dispatches there
+(one microbatch of 4 x 512 tokens).
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and test workers import
+every test file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.runtime.stages import (init_head_params, init_stage_params,
+                                       stage_kernels)
+from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.ssd_scan import ssd_scan_bhsp
+
+HBM_BYTES = 16e9          # one v5e chip
+CHUNK = (4, 512)          # microbatch x sequence, paper Sec. VI
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else the compiler logs to disk
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this install
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back from the
+        # persistent cache without the chip: keep it out of the cache
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda a: _spec(sharding, a.shape, a.dtype), tree)
+
+
+def _device_bytes(compiled) -> float:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def test_flash_attention_compiles(one_chip):
+    q = _spec(one_chip, (64, 512, 64), jnp.bfloat16)
+    compiled = jax.jit(flash_attention_bhsd).lower(q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles(one_chip):
+    cfg = get_config("mamba2-130m")
+    B, H, S = 1, cfg.ssm_heads, 2048
+    P, N = cfg.ssm_head_dim, cfg.ssm_state
+    args = (_spec(one_chip, (B, H, S, P), jnp.bfloat16),
+            _spec(one_chip, (B, H, S), jnp.float32),
+            _spec(one_chip, (H,), jnp.float32),
+            _spec(one_chip, (B, S, N), jnp.bfloat16),
+            _spec(one_chip, (B, S, N), jnp.bfloat16))
+    compiled = jax.jit(ssd_scan_bhsp).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def llama_stage(one_chip):
+    """Abstract full-width stage 0 of 4, its donating kernels, and the
+    chunk that enters it."""
+    cfg = get_config("gwtf-llama-300m")
+    assert (cfg.d_model, cfg.num_layers, cfg.param_dtype) == (
+        1024, 16, "bfloat16")
+    key = jax.random.PRNGKey(0)
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: init_stage_params(cfg, 0, 4, k), key))
+    x = _spec(one_chip, CHUNK + (cfg.d_model,), jnp.bfloat16)
+    return cfg, stage_kernels(cfg, True), params, x
+
+
+def test_stage_forward_with_residuals_fits_one_chip(llama_stage):
+    cfg, k, params, x = llama_stage
+    compiled = k.fwd_res.lower(params, x).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_stage_backward_from_residuals_fits_one_chip(llama_stage, one_chip):
+    cfg, k, params, x = llama_stage
+    _, vjp = jax.eval_shape(k.fwd_res, params, x)
+    compiled = k.bwd_res.lower(_on(one_chip, vjp), x).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_head_loss_fits_one_chip(llama_stage, one_chip):
+    cfg, k, _, _ = llama_stage
+    head = _on(one_chip, jax.eval_shape(
+        lambda key: init_head_params(cfg, key), jax.random.PRNGKey(0)))
+    hidden = _spec(one_chip, (1,) + CHUNK + (cfg.d_model,), jnp.bfloat16)
+    labels = _spec(one_chip, (1,) + CHUNK, jnp.int32)
+    compiled = k.head.lower(head, hidden, labels).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
