@@ -1,0 +1,10 @@
+"""Percent of the traced window in which no operation ran on the
+device (the union of the device's operation intervals is its busy
+time)."""
+
+
+def read(rec):
+    t = rec.trace
+    if not t or not t.get("window_s"):
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
