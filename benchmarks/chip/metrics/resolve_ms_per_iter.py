@@ -1,0 +1,9 @@
+"""Host milliseconds per window iteration in the program's crash
+resolution span ``gwtf.resolve`` (``RecoveryManager.resolve``), from the
+traced run (``program_spans.py``)."""
+from benchmarks.chip.program_spans import of
+
+
+def read(rec):
+    s = of(rec).get("span_s", {}).get("gwtf.resolve")
+    return None if s is None else 1000.0 * s / rec.iterations

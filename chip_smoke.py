@@ -7,8 +7,8 @@
 One chip.  ``gwtf-llama-300m`` at its published config (16 layers,
 d_model 1024, vocab 32000, bf16 params) trains through the staged
 runtime the way ``repro.launch.train --mode gwtf`` builds it: 4 stages
-x 3 relays, 1 data node, 8 microbatches of 4 x 512 tokens.  One warm-up
-iteration, 3 at churn 0, 3 at churn 0.1.  Checks: finite losses, the
+x 3 relays, 1 data node, 8 microbatches of 4 x 512 tokens.  Four
+iterations at churn 0, 3 at churn 0.1.  Checks: finite losses, the
 loss falls over the churn-0 iterations, ``CentralizedTrainer`` gives
 bit-identical churn-0 losses, one microbatch's loss matches a float32
 reference within 2e-2, and the churn phase repairs at least one crash
@@ -21,10 +21,10 @@ and on a one-device mesh of the first chip; the losses must agree within
 2e-2 and each chip must hold about a quarter of the params and AdamW
 state.
 
-Times and memory figures printed on the way are smoke readings of one
-run, not benchmark numbers.  The script exits non-zero, and prints no
-result line, when any check fails or JAX finds no TPU.  A passing run
-ends with one JSON line naming the device.
+It prints no times or memory figures: the chip benchmark
+(``benchmarks/chip/run.py``) measures those.  The script exits non-zero,
+and prints no result line, when any check fails or JAX finds no TPU.  A
+passing run ends with one JSON line naming the device.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ import dataclasses
 import json
 import math
 import sys
-import time
 from pathlib import Path
 
 SRC = Path(__file__).resolve().parent / "src"
@@ -43,7 +42,7 @@ SEED = 2                 # churn schedule chosen so the churn phase repairs
 CHURN = 0.1              # at least one crash (host numpy: width-independent)
 STAGES = 4
 MICROBATCH, SEQ_LEN, N_MICROBATCHES = 4, 512, 8   # paper Sec. VI
-WARMUP_ITERS, CLEAN_ITERS, CHURN_ITERS = 1, 3, 3
+CLEAN_ITERS, CHURN_ITERS = 4, 3
 REF_RTOL = 2e-2
 PROMPT_LEN, GEN_TOKENS = 128, 16
 ARRIVALS = [[0.05, 0.1, 0.15, 0.2], [0.05, 0.1, 0.15, 0.2]]
@@ -71,48 +70,9 @@ class Checks:
             self.failed.append(name)
 
 
-class CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling, from its own
-    monitoring events."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += duration
-
-    def take(self) -> float:
-        s, self.seconds = self.seconds, 0.0
-        return s
-
-
-def reading(msg: str) -> None:
-    print(f"smoke-reading {msg}", flush=True)
-
-
-def peak_bytes() -> int:
-    import jax
-
-    stats = jax.devices()[0].memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", -1))
-
-
 # ---------------------------------------------------------------------------
 # Training
 # ---------------------------------------------------------------------------
-
-def _timed_iteration(trainer, batches):
-    import jax
-
-    t0 = time.perf_counter()
-    r = trainer.iteration(batches)
-    jax.block_until_ready((trainer.stage_params, trainer.head_params))
-    return r, time.perf_counter() - t0
-
 
 def reference_check(check: Checks, trainer, cfg, mb, seed: int) -> None:
     """One microbatch through the timed path's compiled stage programs
@@ -152,61 +112,42 @@ def reference_check(check: Checks, trainer, cfg, mb, seed: int) -> None:
           f"limit={REF_RTOL}")
 
 
-def train_phase(check: Checks, clock: CompileClock, flags) -> None:
+def train_phase(check: Checks, flags) -> None:
     from repro.core.executor import CentralizedTrainer
     from repro.core.sim.faults import BernoulliChurn
     from repro.launch.train import build_parser, make_gwtf
 
     args = build_parser().parse_args(flags)
-    t0 = time.perf_counter()
     cfg, trainer, shards = make_gwtf(args)
-    setup = time.perf_counter() - t0
     print(f"train config: {cfg.name} layers={cfg.num_layers} "
           f"d_model={cfg.d_model} vocab={cfg.vocab_size} "
           f"param_dtype={cfg.param_dtype} stages={args.stages} "
           f"relays/stage={args.relays_per_stage} microbatches="
           f"{args.microbatches}x{args.batch}x{args.seq_len} "
           f"donate={trainer.stages.donate}", flush=True)
-    reading(f"train set-up wall_s={setup!r} compile_s={clock.take()!r}")
     (dn,) = shards
 
     clean_batches = []
-    for it in range(WARMUP_ITERS + CLEAN_ITERS):
+    for _ in range(CLEAN_ITERS):
         batches = {dn: shards[dn].microbatches()}
         clean_batches.append(batches[dn])
-        r, wall = _timed_iteration(trainer, batches)
-        reading(f"train iter {it} churn=0.0 "
-                f"{'warm-up ' if it < WARMUP_ITERS else ''}wall_s={wall!r} "
-                f"compile_s={clock.take()!r} loss={r.loss!r} "
-                f"completed={r.completed}/{r.launched} "
-                f"store_peak_bytes={r.store_peak_bytes}")
+        trainer.iteration(batches)
     clean = list(trainer.losses)
 
     reference_check(check, trainer, cfg, clean_batches[0][0], args.seed)
-    reading(f"reference compile_s={clock.take()!r}")
 
     cen = CentralizedTrainer(cfg, args.stages, lr=args.lr, seed=args.seed)
     for mbs in clean_batches:
         cen.iteration(mbs)
     check("churn0-decentralized==centralized", cen.losses == clean,
           f"decentralized={clean!r} centralized={cen.losses!r}")
-    reading(f"centralized compile_s={clock.take()!r}")
     del cen
 
     trainer.churn_model = BernoulliChurn(CHURN)
     repaired = 0
-    for it in range(CHURN_ITERS):
-        batches = {dn: shards[dn].microbatches()}
-        r, wall = _timed_iteration(trainer, batches)
-        fixes = r.rerouted + r.fwd_recomputes + r.bwd_replays
-        repaired += fixes
-        reading(f"train iter {WARMUP_ITERS + CLEAN_ITERS + it} "
-                f"churn={CHURN} wall_s={wall!r} compile_s={clock.take()!r} "
-                f"loss={r.loss!r} completed={r.completed}/{r.launched} "
-                f"dropped={r.dropped} rerouted={r.rerouted} "
-                f"fwd_recomputes={r.fwd_recomputes} "
-                f"bwd_replays={r.bwd_replays} "
-                f"store_peak_bytes={r.store_peak_bytes}")
+    for _ in range(CHURN_ITERS):
+        r = trainer.iteration({dn: shards[dn].microbatches()})
+        repaired += r.rerouted + r.fwd_recomputes + r.bwd_replays
     losses = trainer.losses
     check("losses-finite", all(math.isfinite(x) for x in losses),
           f"losses={losses!r}")
@@ -214,14 +155,13 @@ def train_phase(check: Checks, clock: CompileClock, flags) -> None:
           f"first={clean[0]!r} after_churn0={clean[-1]!r}")
     check("churn-phase-repairs-a-crash", repaired > 0,
           f"rerouted+fwd_recomputes+bwd_replays={repaired}")
-    reading(f"train device peak_bytes_in_use={peak_bytes()}")
 
 
 # ---------------------------------------------------------------------------
 # Decode
 # ---------------------------------------------------------------------------
 
-def decode_phase(check: Checks, clock: CompileClock, cfg) -> None:
+def decode_phase(check: Checks, cfg) -> None:
     import numpy as np
 
     from repro.core.flow.graph import geo_distributed_network
@@ -234,7 +174,6 @@ def decode_phase(check: Checks, clock: CompileClock, cfg) -> None:
         num_stages=STAGES, relay_capacities=[4] * (2 * STAGES),
         num_data_nodes=1, data_capacity=N_MICROBATCHES, rng=rng)
     n_req = sum(len(a) for a in ARRIVALS)
-    t0 = time.perf_counter()
     serve = ServeTrainer(
         cfg, net, policy=GWTFPolicy(net, rng=rng), arrival_program=ARRIVALS,
         profile=ModelProfile.from_config(cfg, num_stages=STAGES,
@@ -243,21 +182,10 @@ def decode_phase(check: Checks, clock: CompileClock, cfg) -> None:
         prompt_len=PROMPT_LEN, gen_tokens=GEN_TOKENS, serve_batch=4,
         tokens_per_mb=MICROBATCH * SEQ_LEN, rng=rng, seed=SEED,
         max_requests=n_req)
-    setup = time.perf_counter() - t0
     print(f"decode config: {cfg.name} d_model={cfg.d_model} "
           f"prompt_len={PROMPT_LEN} gen_tokens={GEN_TOKENS} "
           f"requests={n_req} iterations={len(ARRIVALS)}", flush=True)
-    reading(f"decode set-up wall_s={setup!r} compile_s={clock.take()!r}")
-    admitted = 0
-    for it in range(len(ARRIVALS)):
-        t0 = time.perf_counter()
-        m = serve.iteration()
-        wall = time.perf_counter() - t0
-        admitted += m.admitted
-        reading(f"decode iter {it} wall_s={wall!r} "
-                f"compile_s={clock.take()!r} admitted={m.admitted} "
-                f"completed={m.completed} in_flight={m.in_flight} "
-                f"decode_dispatches={serve.decode_dispatches}")
+    admitted = sum(serve.iteration().admitted for _ in ARRIVALS)
     recs = serve.engine.requests
     done = [rid for rid, rec in recs.items() if rec.completion is not None]
     streams = {rid: serve.token_stream(rid) for rid in recs}
@@ -267,7 +195,6 @@ def decode_phase(check: Checks, clock: CompileClock, cfg) -> None:
           admitted == n_req and len(good) == len(recs) == n_req,
           f"admitted={admitted} completed={len(done)} "
           f"with_{GEN_TOKENS}_in_vocab_tokens={len(good)} of {n_req}")
-    reading(f"decode device peak_bytes_in_use={peak_bytes()}")
 
 
 # ---------------------------------------------------------------------------
@@ -284,50 +211,37 @@ def _bytes_by_device(tree):
     return out
 
 
-def _spmd_run(clock: CompileClock, flags, mesh, label: str):
+def _spmd_run(flags, mesh, label: str):
     import jax
 
     from repro.launch.train import build_parser, make_spmd
 
     args = build_parser().parse_args(flags)
-    t0 = time.perf_counter()
     cfg, step_fn, params, opt_state, shard = make_spmd(args, mesh)
-    jax.block_until_ready((params, opt_state))
-    setup = time.perf_counter() - t0
     total = sum(x.nbytes for x in jax.tree.leaves((params, opt_state)))
     held = _bytes_by_device((params, opt_state))
-    in_use = {d.id: (d.memory_stats() or {}).get("bytes_in_use", -1)
-              for d in mesh.devices.flat}
     print(f"spmd {label}: {cfg.name} layers={cfg.num_layers} "
           f"d_model={cfg.d_model} vocab={cfg.vocab_size} "
           f"param_dtype={cfg.param_dtype} mesh={dict(mesh.shape)} "
           f"batch={args.batch}x{args.seq_len}", flush=True)
-    reading(f"spmd {label} set-up wall_s={setup!r} "
-            f"compile_s={clock.take()!r} state_bytes={total} "
-            f"held_by_device={held} bytes_in_use_by_device={in_use}")
     losses = []
-    for step in range(SPMD_STEPS):
-        t0 = time.perf_counter()
+    for _ in range(SPMD_STEPS):
         params, opt_state, loss = step_fn(params, opt_state,
                                           shard.next_batch())
         losses.append(float(loss))
-        jax.block_until_ready((params, opt_state))
-        reading(f"spmd {label} step {step} wall_s="
-                f"{time.perf_counter() - t0!r} compile_s={clock.take()!r} "
-                f"loss={losses[-1]!r}")
     return losses, held, total
 
 
-def spmd_phase(check: Checks, clock: CompileClock, flags, devices) -> None:
+def spmd_phase(check: Checks, flags, devices) -> None:
     from repro.launch.mesh import make_host_mesh
 
     n = len(devices)
-    multi, held, total = _spmd_run(clock, flags, make_host_mesh(devices),
+    multi, held, total = _spmd_run(flags, make_host_mesh(devices),
                                    f"{n}-device")
     share = max(held.values()) / total
     check("spmd-state-sharded", len(held) == n and share <= 1.5 / n,
           f"largest_device_share={share:.4f} devices={len(held)}")
-    single, _, _ = _spmd_run(clock, flags, make_host_mesh(devices[:1]),
+    single, _, _ = _spmd_run(flags, make_host_mesh(devices[:1]),
                              "1-device")
     rel = max(abs(a - b) / abs(b) for a, b in zip(multi, single))
     check(f"spmd-{n}-device-vs-1-device",
@@ -363,13 +277,13 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}", flush=True)
-    check, clock = Checks(), CompileClock()
+    check = Checks()
     if args.chips == 4:
-        spmd_phase(check, clock, SPMD_FLAGS, devices[:4])
+        spmd_phase(check, SPMD_FLAGS, devices[:4])
     else:
-        train_phase(check, clock, TRAIN_FLAGS)
+        train_phase(check, TRAIN_FLAGS)
         from repro.configs import get_config
-        decode_phase(check, clock, get_config(ARCH))
+        decode_phase(check, get_config(ARCH))
     if check.failed:
         print(f"chip_smoke: FAILED {check.failed}", file=sys.stderr)
         return 1

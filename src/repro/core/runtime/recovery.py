@@ -367,6 +367,7 @@ class RecoveryManager:
         reuses) the live buffer on donating backends.
         """
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
         ids = tuple(ids)
         for ev in res.events:
@@ -374,26 +375,27 @@ class RecoveryManager:
                 continue
             if ev.job not in ids:
                 continue    # dropped, or belongs to another chunk
-            if direction == "fwd":
+            with TraceAnnotation("gwtf.repair"):
+                if direction == "fwd":
+                    try:
+                        xin = store.get(s, ev.job)
+                    except KeyError:
+                        continue
+                    stages.forward(s, stage_params[s], xin)
+                    continue
+                if cotangent is None:
+                    continue
+                if not remat and store.has_residuals(s, ids):
+                    stages.backward_from_residuals(
+                        s, store.residuals(s, ids), jnp.copy(cotangent))
+                    continue
                 try:
                     xin = store.get(s, ev.job)
                 except KeyError:
                     continue
-                stages.forward(s, stage_params[s], xin)
-                continue
-            if cotangent is None:
-                continue
-            if not remat and store.has_residuals(s, ids):
-                stages.backward_from_residuals(
-                    s, store.residuals(s, ids), jnp.copy(cotangent))
-                continue
-            try:
-                xin = store.get(s, ev.job)
-            except KeyError:
-                continue
-            k = ids.index(ev.job)
-            stages.backward(s, stage_params[s], xin,
-                            jnp.copy(cotangent[k * per:(k + 1) * per]))
+                k = ids.index(ev.job)
+                stages.backward(s, stage_params[s], xin,
+                                jnp.copy(cotangent[k * per:(k + 1) * per]))
 
     @staticmethod
     def _count_recompute(direction: str, res: Resolution) -> None:

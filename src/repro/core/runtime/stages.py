@@ -133,7 +133,10 @@ def stage_kernels(cfg: ModelConfig, donate: bool) -> StageKernels:
     stage and every stage count; the cache key is the hashable frozen
     ``ModelConfig`` plus the donation flag.
     """
-    fwd = jax.jit(lambda p, x: stage_forward(p, x, cfg))
+    def fwd_impl(p, x):
+        return stage_forward(p, x, cfg)
+
+    fwd = jax.jit(fwd_impl)
 
     def fwd_res_impl(p, x):
         # jax.vjp inside jit: the returned closure is a

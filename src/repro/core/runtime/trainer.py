@@ -32,6 +32,15 @@
    replicas stay bit-identical), and stage snapshots are written to
    ``checkpoint.store`` every ``checkpoint_every`` iterations.
 
+Each step above runs inside a ``jax.profiler`` host span named
+``gwtf.<step>`` (``gwtf.iteration`` around the whole call, then
+``gwtf.churn``, ``gwtf.plan``, ``gwtf.resolve``, ``gwtf.execute`` and its
+chunk-level spans, ``gwtf.update``, ``gwtf.commit``), so a profiler
+trace puts every device-idle gap down to the host work around it.  The
+spans record only while a profiler session is open and cost under a
+microsecond each otherwise.  ``IterationResult.host_syncs`` counts the
+blocking device-to-host reads of the iteration (one per dispatch chunk).
+
 `CentralizedTrainer` (the Fig. 6 baseline) lives here too and runs the
 *same* chunked pass (`_chunk_pass`) over the same cached kernels, so
 at churn 0 the decentralized trainer executes bit-for-bit the
@@ -47,6 +56,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import store as ckpt
 from repro.core.flow.graph import FlowNetwork, Node
@@ -130,11 +141,12 @@ def _chunk_pass(stages: StageCompute, store: ActivationStore,
     x = stages.embed(head_params, toks)
     for s in range(S):
         store.put(s, ids, x)
-        if remat:
-            x = stages.forward(s, stage_params[s], x)
-        else:
-            x, resid = stages.forward_fused(s, stage_params[s], x)
-            store.put_residuals(s, ids, resid)
+        with TraceAnnotation("gwtf.forward"):
+            if remat:
+                x = stages.forward(s, stage_params[s], x)
+            else:
+                x, resid = stages.forward_fused(s, stage_params[s], x)
+                store.put_residuals(s, ids, resid)
         if replay is not None:
             replay(s, "fwd", ids)
         if wire is not None and s < S - 1:
@@ -142,23 +154,32 @@ def _chunk_pass(stages: StageCompute, store: ActivationStore,
     B = len(ids)
     seq, D = x.shape[1], x.shape[-1]
     h = x.reshape(B, per, seq, D)
-    losses, g_head, g_hidden = stages.head_loss(head_params, h, labels)
+    with TraceAnnotation("gwtf.head"):
+        losses, g_head, g_hidden = stages.head_loss(head_params, h, labels)
     g = g_hidden.reshape(B * per, seq, D)
     for s in reversed(range(S)):
         if replay is not None:
             replay(s, "bwd", ids, g, per)
-        if remat:
-            xin = store.stacked(s, ids)
-            dp, dx = stages.backward(s, stage_params[s], xin, g)
+        with TraceAnnotation("gwtf.backward"):
+            if remat:
+                xin = store.stacked(s, ids)
+                dp, dx = stages.backward(s, stage_params[s], xin, g)
+            else:
+                dp, dx = stages.backward_from_residuals(
+                    s, store.residuals(s, ids), g)
+        if grad_stage[s] is None:
+            grad_stage[s] = dp
         else:
-            dp, dx = stages.backward_from_residuals(
-                s, store.residuals(s, ids), g)
-        grad_stage[s] = (dp if grad_stage[s] is None else
-                        jax.tree.map(jnp.add, grad_stage[s], dp))
+            with TraceAnnotation("gwtf.accumulate"):
+                grad_stage[s] = jax.tree.map(jnp.add, grad_stage[s], dp)
         g = dx
         store.drop(s, ids)
     g_emb = stages.embed_backward(head_params, toks, g)
-    return float(jnp.sum(losses)), jax.tree.map(jnp.add, g_head, g_emb)
+    with TraceAnnotation("gwtf.loss_sync"):
+        loss_sum = float(jnp.sum(losses))
+    with TraceAnnotation("gwtf.accumulate"):
+        g_head = jax.tree.map(jnp.add, g_head, g_emb)
+    return loss_sum, g_head
 
 
 @dataclass
@@ -183,6 +204,17 @@ class IterationResult:
     grads_flagged: int = 0        # contributions the gradient screen
                                   # excluded from this update (the jobs
                                   # still count as completed)
+    host_syncs: int = 0           # blocking device-to-host reads (one
+                                  # per dispatch chunk's losses)
+
+
+def _jit_adamw(opt: AdamW):
+    """The jitted AdamW step, named so the device trace reads
+    ``jit_adamw_update``."""
+    def adamw_update(grads, state, params):
+        return opt.update(grads, state, params)
+
+    return jax.jit(adamw_update)
 
 
 class RuntimeTrainer:
@@ -253,7 +285,7 @@ class RuntimeTrainer:
         self.stage_opt = [self.opt.init(p) for p in self.stage_params]
         self.head_opt = {d: self.opt.init(p)
                          for d, p in self.head_params.items()}
-        self._upd = jax.jit(lambda g, s, p: self.opt.update(g, s, p))
+        self._upd = _jit_adamw(self.opt)
 
         self.losses: List[float] = []
         self.step = 0
@@ -376,35 +408,44 @@ class RuntimeTrainer:
     # ------------------------------------------------------------------
     def iteration(self, batches_per_data_node: Dict[int, List[dict]]
                   ) -> IterationResult:
+        with StepTraceAnnotation("gwtf.iteration", step_num=self.step):
+            return self._iteration(batches_per_data_node)
+
+    def _iteration(self, batches_per_data_node: Dict[int, List[dict]]
+                   ) -> IterationResult:
         horizon = 1.0                    # normalized pipeline-flush clock
         it = self.step
-        crash_times = self.churn_model.sample(ChurnContext(
-            net=self.net, rng=self.rng, horizon=horizon,
-            iteration=it, on_rejoin=self._on_rejoin))
-        # adversarial side channel — None for plain fail-stop models,
-        # keeping every defended branch below inert.  Injections are
-        # recorded from the same model outputs the simulator records
-        # from, which is what makes the two layers' timelines
-        # injection-count identical by construction.
-        adv = adversarial_plan(self.churn_model, it)
-        record_injections(self.timeline, it, crash_times, adv)
+        with TraceAnnotation("gwtf.churn"):
+            crash_times = self.churn_model.sample(ChurnContext(
+                net=self.net, rng=self.rng, horizon=horizon,
+                iteration=it, on_rejoin=self._on_rejoin))
+            # adversarial side channel — None for plain fail-stop models,
+            # keeping every defended branch below inert.  Injections are
+            # recorded from the same model outputs the simulator records
+            # from, which is what makes the two layers' timelines
+            # injection-count identical by construction.
+            adv = adversarial_plan(self.churn_model, it)
+            record_injections(self.timeline, it, crash_times, adv)
 
-        chains = [list(c) for c in self.policy.plan()]
-        jobs: List[Job] = []
-        per_dn: Dict[int, int] = {}
-        for chain in chains:
-            dn = chain[0]
-            avail = batches_per_data_node.get(dn, [])
-            k = per_dn.get(dn, 0)
-            if k < len(avail):
-                jobs.append(Job(index=len(jobs), data_node=dn,
-                                mb=avail[k], chain=list(chain)))
-                per_dn[dn] = k + 1
+        with TraceAnnotation("gwtf.plan"):
+            chains = [list(c) for c in self.policy.plan()]
+            jobs: List[Job] = []
+            per_dn: Dict[int, int] = {}
+            for chain in chains:
+                dn = chain[0]
+                avail = batches_per_data_node.get(dn, [])
+                k = per_dn.get(dn, 0)
+                if k < len(avail):
+                    jobs.append(Job(index=len(jobs), data_node=dn,
+                                    mb=avail[k], chain=list(chain)))
+                    per_dn[dn] = k + 1
         launched = len(jobs)
 
-        res = self.recovery.resolve(jobs, chains, crash_times, horizon,
-                                    adv=adv, timeline=self.timeline,
-                                    iteration=it)
+        with TraceAnnotation("gwtf.resolve"):
+            res = self.recovery.resolve(jobs, chains, crash_times, horizon,
+                                        adv=adv, timeline=self.timeline,
+                                        iteration=it)
+
         self.last_chains = chains
         self.last_resolution = res
 
@@ -430,24 +471,25 @@ class RuntimeTrainer:
         mean_loss = self._execute(res, wire)
         self.last_wire_bytes = wire.bytes if wire is not None else 0
 
-        # ---- commit crashes for the next iteration --------------------
-        for nid in crash_times:
-            self.net.kill_node(nid)
-            self.policy.on_crash(nid)
+        with TraceAnnotation("gwtf.commit"):
+            # ---- commit crashes for the next iteration ----------------
+            for nid in crash_times:
+                self.net.kill_node(nid)
+                self.policy.on_crash(nid)
 
-        # ---- reputation: decay first (rehabilitation), then charge
-        # this iteration's detections (fresh faults carry the full
-        # quarantine penalty into the next plan).  Same ordering as the
-        # sim engine; both no-op bit-identically on clean runs.
-        if res.rep_reports or self.net.reputation_active():
-            self.net.decay_reputations()
-            for r_nid in res.rep_reports:
-                self.net.report_fault(r_nid)
+            # ---- reputation: decay first (rehabilitation), then charge
+            # this iteration's detections (fresh faults carry the full
+            # quarantine penalty into the next plan).  Same ordering as
+            # the sim engine; both no-op bit-identically on clean runs.
+            if res.rep_reports or self.net.reputation_active():
+                self.net.decay_reputations()
+                for r_nid in res.rep_reports:
+                    self.net.report_fault(r_nid)
 
-        self.step += 1
-        if (self.checkpoint_dir and self.checkpoint_every
-                and self.step % self.checkpoint_every == 0):
-            self.save_checkpoint()
+            self.step += 1
+            if (self.checkpoint_dir and self.checkpoint_every
+                    and self.step % self.checkpoint_every == 0):
+                self.save_checkpoint()
 
         self.losses.append(mean_loss)
         return IterationResult(
@@ -459,7 +501,8 @@ class RuntimeTrainer:
             wire_bytes=self.last_wire_bytes,
             wire_codecs=tuple(self.last_wire_codecs),
             deadline_requeues=res.deadline_requeues,
-            grads_flagged=self._grads_flagged)
+            grads_flagged=self._grads_flagged,
+            host_syncs=self._host_syncs)
 
     # ------------------------------------------------------------------
     # Numeric pass
@@ -470,6 +513,7 @@ class RuntimeTrainer:
         apply the aggregated update; dispatch each recorded crash's
         lost work so recovery cost is real."""
         done = res.completed
+        self._host_syncs = 0
         self.store.clear()
         self.store.reset_peak()
         self.last_store_peak_bytes = 0
@@ -481,10 +525,11 @@ class RuntimeTrainer:
         # screen needs per-job contributions before aggregation
         adversarial = (bool(getattr(self, "_corrupt_stages", None))
                        or getattr(self, "_screen", False))
-        if self.batch_microbatches and not adversarial:
-            total = self._execute_batched(done, res, wire)
-        else:
-            total = self._execute_per_microbatch(done, res, wire)
+        with TraceAnnotation("gwtf.execute"):
+            if self.batch_microbatches and not adversarial:
+                total = self._execute_batched(done, res, wire)
+            else:
+                total = self._execute_per_microbatch(done, res, wire)
         self.last_store_peak_bytes = self.store.peak_bytes
         self.store.clear()
         return total / len(done)
@@ -522,19 +567,25 @@ class RuntimeTrainer:
             head_p = self.head_params[dn]
             g_head = None
             for lo in range(0, len(idxs), C):
-                jobs = [done[k] for k in idxs[lo:lo + C]]
-                ids = tuple(j.index for j in jobs)
-                toks = jnp.asarray(np.concatenate(
-                    [np.asarray(j.mb["tokens"]) for j in jobs]))
-                labels = jnp.asarray(np.stack(
-                    [np.asarray(j.mb["labels"]) for j in jobs]))
-                loss_sum, gh = _chunk_pass(
-                    self.stages, self.store, self.stage_params, head_p,
-                    toks, labels, ids, per, remat=self.remat,
-                    grad_stage=grad_stage, replay=replay, wire=wire)
-                total += loss_sum
-                g_head = (gh if g_head is None else
-                          jax.tree.map(jnp.add, g_head, gh))
+                with TraceAnnotation("gwtf.chunk"):
+                    jobs = [done[k] for k in idxs[lo:lo + C]]
+                    ids = tuple(j.index for j in jobs)
+                    with TraceAnnotation("gwtf.feed"):
+                        toks = jnp.asarray(np.concatenate(
+                            [np.asarray(j.mb["tokens"]) for j in jobs]))
+                        labels = jnp.asarray(np.stack(
+                            [np.asarray(j.mb["labels"]) for j in jobs]))
+                    loss_sum, gh = _chunk_pass(
+                        self.stages, self.store, self.stage_params, head_p,
+                        toks, labels, ids, per, remat=self.remat,
+                        grad_stage=grad_stage, replay=replay, wire=wire)
+                    self._host_syncs += 1      # the chunk's loss read
+                    total += loss_sum
+                    if g_head is None:
+                        g_head = gh
+                    else:
+                        with TraceAnnotation("gwtf.accumulate"):
+                            g_head = jax.tree.map(jnp.add, g_head, gh)
             g_head_by_dn[dn] = (g_head, len(idxs))
         self._apply_update(grad_stage, g_head_by_dn, len(done))
         return total
@@ -631,82 +682,91 @@ class RuntimeTrainer:
             key = (ev.job, ev.stage, ev.direction)
             lost[key] = lost.get(key, 0) + 1
         for job in done:
-            toks = jnp.asarray(job.mb["tokens"])
-            labels = jnp.asarray(job.mb["labels"])[None]
-            ids = (job.index,)
-            x = self.stages.embed(self.head_params[job.data_node], toks)
-            for s in range(S):
-                self.store.put(s, ids, x)
-                for _ in range(lost.get((job.index, s, "fwd"), 0)):
-                    self.stages.forward(s, self.stage_params[s], x)
-                if self.remat:
-                    x = self.stages.forward(s, self.stage_params[s], x)
-                else:
-                    x, resid = self.stages.forward_fused(
-                        s, self.stage_params[s], x)
-                    self.store.put_residuals(s, ids, resid)
-                if wire is not None and s < S - 1:
-                    x = wire.send(s, x)
-            losses, g_head, g_hidden = self.stages.head_loss(
-                self.head_params[job.data_node], x[None], labels)
-            total += float(losses[0])
-            g = g_hidden[0]
-            g_stages: List[Any] = [None] * S
-            for s in reversed(range(S)):
-                for _ in range(lost.get((job.index, s, "bwd"), 0)):
-                    # copied cotangent: the backward dispatch donates
-                    # its cotangent buffer on donating backends and g
-                    # is reused by the real dispatch below
-                    if not self.remat and self.store.has_residuals(s, ids):
-                        self.stages.backward_from_residuals(
-                            s, self.store.residuals(s, ids), jnp.copy(g))
+            with TraceAnnotation("gwtf.chunk"):
+                with TraceAnnotation("gwtf.feed"):
+                    toks = jnp.asarray(job.mb["tokens"])
+                    labels = jnp.asarray(job.mb["labels"])[None]
+                ids = (job.index,)
+                x = self.stages.embed(self.head_params[job.data_node], toks)
+                for s in range(S):
+                    self.store.put(s, ids, x)
+                    for _ in range(lost.get((job.index, s, "fwd"), 0)):
+                        self.stages.forward(s, self.stage_params[s], x)
+                    if self.remat:
+                        x = self.stages.forward(s, self.stage_params[s], x)
                     else:
-                        self.stages.backward(
+                        x, resid = self.stages.forward_fused(
+                            s, self.stage_params[s], x)
+                        self.store.put_residuals(s, ids, resid)
+                    if wire is not None and s < S - 1:
+                        x = wire.send(s, x)
+                losses, g_head, g_hidden = self.stages.head_loss(
+                    self.head_params[job.data_node], x[None], labels)
+                with TraceAnnotation("gwtf.loss_sync"):
+                    total += float(losses[0])
+                self._host_syncs += 1
+                g = g_hidden[0]
+                g_stages: List[Any] = [None] * S
+                for s in reversed(range(S)):
+                    for _ in range(lost.get((job.index, s, "bwd"), 0)):
+                        # copied cotangent: the backward dispatch donates
+                        # its cotangent buffer on donating backends and g
+                        # is reused by the real dispatch below
+                        if (not self.remat
+                                and self.store.has_residuals(s, ids)):
+                            self.stages.backward_from_residuals(
+                                s, self.store.residuals(s, ids),
+                                jnp.copy(g))
+                        else:
+                            self.stages.backward(
+                                s, self.stage_params[s],
+                                self.store.get(s, job.index), jnp.copy(g))
+                    if self.remat:
+                        dp, dx = self.stages.backward(
                             s, self.stage_params[s],
-                            self.store.get(s, job.index), jnp.copy(g))
-                if self.remat:
-                    dp, dx = self.stages.backward(
-                        s, self.stage_params[s],
-                        self.store.get(s, job.index), g)
-                else:
-                    dp, dx = self.stages.backward_from_residuals(
-                        s, self.store.residuals(s, ids), g)
-                hit = corrupt_stages.get(job.index)
-                if hit is not None and s in hit:
-                    # the corrupt relay at this stage perturbs the
-                    # backward results it computed; the poisoned
-                    # cotangent dx flows into every earlier stage
-                    mode, scale, c_seed, _nid = hit[s]
-                    dp = self._perturb_tree(dp, mode, scale, c_seed,
-                                            job.index, s)
-                    dx = self._perturb_tree(dx, mode, scale, c_seed,
-                                            job.index, s)
-                g_stages[s] = dp
-                g = dx
-                self.store.drop(s, ids)
-            g_emb = self.stages.embed_backward(
-                self.head_params[job.data_node], toks, g)
-            g_head = jax.tree.map(jnp.add, g_head, g_emb)
-            if self.record_microbatch_grads:
-                self.last_microbatch_grads.append(
-                    (job.index, g_head, list(g_stages)))
-            if collect:
-                # defer aggregation until the screen has seen every
-                # contribution (same jnp.add chain in the same job
-                # order afterwards, so an empty flag set aggregates
-                # bit-identically to the inline path)
-                contribs.append((job, g_head, g_stages))
-                continue
-            for s in range(S):
-                grad_stage[s] = (g_stages[s] if grad_stage[s] is None else
-                                 jax.tree.map(jnp.add, grad_stage[s],
-                                              g_stages[s]))
-            dn = job.data_node
-            if dn in g_head_by_dn:
-                acc, n = g_head_by_dn[dn]
-                g_head_by_dn[dn] = (jax.tree.map(jnp.add, acc, g_head), n + 1)
-            else:
-                g_head_by_dn[dn] = (g_head, 1)
+                            self.store.get(s, job.index), g)
+                    else:
+                        dp, dx = self.stages.backward_from_residuals(
+                            s, self.store.residuals(s, ids), g)
+                    hit = corrupt_stages.get(job.index)
+                    if hit is not None and s in hit:
+                        # the corrupt relay at this stage perturbs the
+                        # backward results it computed; the poisoned
+                        # cotangent dx flows into every earlier stage
+                        mode, scale, c_seed, _nid = hit[s]
+                        dp = self._perturb_tree(dp, mode, scale, c_seed,
+                                                job.index, s)
+                        dx = self._perturb_tree(dx, mode, scale, c_seed,
+                                                job.index, s)
+                    g_stages[s] = dp
+                    g = dx
+                    self.store.drop(s, ids)
+                g_emb = self.stages.embed_backward(
+                    self.head_params[job.data_node], toks, g)
+                with TraceAnnotation("gwtf.accumulate"):
+                    g_head = jax.tree.map(jnp.add, g_head, g_emb)
+                if self.record_microbatch_grads:
+                    self.last_microbatch_grads.append(
+                        (job.index, g_head, list(g_stages)))
+                if collect:
+                    # defer aggregation until the screen has seen every
+                    # contribution (same jnp.add chain in the same job
+                    # order afterwards, so an empty flag set aggregates
+                    # bit-identically to the inline path)
+                    contribs.append((job, g_head, g_stages))
+                    continue
+                with TraceAnnotation("gwtf.accumulate"):
+                    for s in range(S):
+                        grad_stage[s] = (
+                            g_stages[s] if grad_stage[s] is None else
+                            jax.tree.map(jnp.add, grad_stage[s], g_stages[s]))
+                    dn = job.data_node
+                    if dn in g_head_by_dn:
+                        acc, n = g_head_by_dn[dn]
+                        g_head_by_dn[dn] = (jax.tree.map(jnp.add, acc, g_head),
+                                            n + 1)
+                    else:
+                        g_head_by_dn[dn] = (g_head, 1)
         if collect:
             flagged = self._screen_contribs(contribs) if screening else set()
             self._grads_flagged = len(flagged)
@@ -744,18 +804,19 @@ class RuntimeTrainer:
         return total
 
     def _apply_update(self, grad_stage, g_head_by_dn, n_completed: int):
-        for s in range(self.net.num_stages):
-            if grad_stage[s] is None:
-                continue
-            gs = jax.tree.map(lambda a: a / n_completed, grad_stage[s])
-            self.stage_params[s], self.stage_opt[s] = self._upd(
-                gs, self.stage_opt[s], self.stage_params[s])
-        for dn, (gh, n) in g_head_by_dn.items():
-            if gh is None:
-                continue
-            g = jax.tree.map(lambda a: a / n, gh)
-            self.head_params[dn], self.head_opt[dn] = self._upd(
-                g, self.head_opt[dn], self.head_params[dn])
+        with TraceAnnotation("gwtf.update"):
+            for s in range(self.net.num_stages):
+                if grad_stage[s] is None:
+                    continue
+                gs = jax.tree.map(lambda a: a / n_completed, grad_stage[s])
+                self.stage_params[s], self.stage_opt[s] = self._upd(
+                    gs, self.stage_opt[s], self.stage_params[s])
+            for dn, (gh, n) in g_head_by_dn.items():
+                if gh is None:
+                    continue
+                g = jax.tree.map(lambda a: a / n, gh)
+                self.head_params[dn], self.head_opt[dn] = self._upd(
+                    g, self.head_opt[dn], self.head_params[dn])
 
 
 class CentralizedTrainer:
@@ -793,7 +854,7 @@ class CentralizedTrainer:
         self.head_opt = self.opt.init(self.head_params)
         self.stages = StageCompute(cfg, num_stages, donate=donate)
         self.store = ActivationStore(codec=activation_codec)
-        self._upd = jax.jit(lambda g, s, p: self.opt.update(g, s, p))
+        self._upd = _jit_adamw(self.opt)
         self.losses: List[float] = []
         self.last_store_peak_bytes = 0
         self.last_wire_bytes = 0
