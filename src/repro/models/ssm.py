@@ -1,15 +1,18 @@
 """Mamba2 / SSD (state-space duality) block — arXiv:2405.21060.
 
-Implements the chunked SSD algorithm: intra-chunk quadratic (attention-like)
-term + inter-chunk recurrence carried by ``lax.scan``.  A single-step decode
-path maintains (conv_state, ssm_state) caches for O(1) per-token decoding —
-this is what makes ``long_500k`` tractable for the ssm/hybrid archs.
+Implements the chunked SSD algorithm for all chunks at once: intra-chunk
+quadratic (attention-like) term + inter-chunk recurrence as one small
+masked decay matrix over the chunk axis, with no sequential loop.  A
+single-step decode path maintains (conv_state, ssm_state) caches for O(1)
+per-token decoding — this is what makes ``long_500k`` tractable for the
+ssm/hybrid archs.
 
 The pure-jnp math here doubles as the oracle for the Pallas ``ssd_scan``
 kernel (see repro/kernels/ref.py which re-exports ``ssd_reference``).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -49,53 +52,66 @@ def ssd_reference(x, dt, A, B, C, h0=None):
 
 
 def ssd_chunked(x, dt, A, B, C, h0=None, chunk: int = 64):
-    """Chunked SSD: O(S*Q) intra-chunk matmuls + O(S/Q) sequential scan.
+    """Chunked SSD, every chunk at once (arXiv:2405.21060, Sec. 6).
 
     Same signature/semantics as ``ssd_reference`` (float32 internal math).
+    Head-major layout ``(b, H, c, Q, .)`` keeps the chunk length Q next to
+    P or N in the minor dimensions.  Every contraction has two operands,
+    so no ``(b, H, c, Q, Q, P)`` intermediate can appear.
+
+    Under differentiation the backward pass recomputes the chunks from the
+    inputs (``jax.checkpoint``): stored, their ``(b, H, c, Q, Q)``,
+    ``(b, H, c, Q, P)`` and per-chunk state intermediates would take as
+    many residual bytes as the rest of the Mamba-2 block, for little compute.
     """
     b, S, H, P = x.shape
     N = B.shape[-1]
     assert S % chunk == 0, f"S={S} % chunk={chunk}"
-    nc = S // chunk
     if h0 is None:
         h0 = jnp.zeros((b, H, P, N), jnp.float32)
+    return _ssd_all_chunks(x, dt, A, B, C, h0, chunk)
 
-    xf = x.astype(jnp.float32).reshape(b, nc, chunk, H, P)
-    dtf = dt.astype(jnp.float32).reshape(b, nc, chunk, H)
-    Bf = B.astype(jnp.float32).reshape(b, nc, chunk, N)
-    Cf = C.astype(jnp.float32).reshape(b, nc, chunk, N)
 
-    # move chunk dim to front for scan
-    xs = (xf.transpose(1, 0, 2, 3, 4), dtf.transpose(1, 0, 2, 3),
-          Bf.transpose(1, 0, 2, 3), Cf.transpose(1, 0, 2, 3))
+@functools.partial(jax.checkpoint, static_argnums=(6,))
+def _ssd_all_chunks(x, dt, A, B, C, h0, chunk):
+    b, S, H, P = x.shape
+    N = B.shape[-1]
+    nc = S // chunk
+    f32 = jnp.float32
+    dtf = dt.astype(f32).reshape(b, nc, chunk, H).transpose(0, 3, 1, 2)
+    # dt folded into x: X[s] = dt_s x_s                    (b,H,c,Q,P)
+    X = (x.astype(f32).reshape(b, nc, chunk, H, P).transpose(0, 3, 1, 2, 4)
+         * dtf[..., None])
+    Bf = B.astype(f32).reshape(b, nc, chunk, N)            # (b,c,Q,N)
+    Cf = C.astype(f32).reshape(b, nc, chunk, N)
+    L = jnp.cumsum(dtf * A[:, None, None], axis=-1)  # (b,H,c,Q) log decay
 
-    idx = jnp.arange(chunk)
-    causal = idx[:, None] >= idx[None, :]                 # (Q, Q) s <= t
+    # intra-chunk: y[t] = sum_{s<=t} exp(L[t]-L[s]) (C[t].B[s]) X[s].  Mask
+    # the exponent *before* exp: the s>t half would overflow to +inf (L is
+    # non-increasing), and exp's gradient there would poison the rest.
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, L[..., :, None] - L[..., None, :],
+                              -jnp.inf))                   # (b,H,c,Q,Q)
+    CB = jnp.einsum("bctn,bcsn->bcts", Cf, Bf)             # (b,c,Q,Q)
+    y = jnp.einsum("bhcts,bhcsp->bhctp", decay * CB[:, None], X)
 
-    def per_chunk(h, inp):
-        xc, dtc, Bc, Cc = inp            # (b,Q,H,P) (b,Q,H) (b,Q,N) (b,Q,N)
-        loga = dtc * A                   # (b,Q,H) log decay per step
-        L = jnp.cumsum(loga, axis=1)     # inclusive cumulative log decay
-        # intra-chunk: M[t,s] = exp(L[t]-L[s]) * dt[s] * (C[t].B[s]), s<=t
-        CB = jnp.einsum("btn,bsn->bts", Cc, Bc)            # (b,Q,Q)
-        delta = L[:, :, None, :] - L[:, None, :, :]        # (b,t,s,H)
-        # mask the exponent *before* exp: the s>t half would overflow to
-        # +inf (L is non-increasing) and poison gradients through where().
-        delta = jnp.where(causal[None, :, :, None], delta, 0.0)
-        M = CB[..., None] * jnp.exp(delta) * dtc[:, None, :, :]
-        M = jnp.where(causal[None, :, :, None], M, 0.0)
-        y_intra = jnp.einsum("btsh,bshp->bthp", M, xc)
-        # contribution of the incoming state: y += exp(L[t]) * C[t] . h
-        y_state = jnp.einsum("bhpn,btn->bthp", h, Cc) * jnp.exp(L)[..., None]
-        # new state: h' = exp(L[Q-1]) h + sum_s exp(L[Q-1]-L[s]) dt_s x_s (x) B_s
-        last = L[:, -1:, :]                                # (b,1,H)
-        w = jnp.exp(last - L) * dtc                        # (b,Q,H)
-        h_new = jnp.exp(last[:, 0])[:, :, None, None] * h + \
-            jnp.einsum("bqh,bqhp,bqn->bhpn", w, xc, Bc)
-        return h_new, y_intra + y_state
+    # chunk-end states: sum_s exp(L[Q-1]-L[s]) X[s] (x) B[s]  (b,H,c,P,N)
+    tail = jnp.exp(L[..., -1:] - L)
+    states = jnp.einsum("bhcsp,bcsn->bhcpn", X * tail[..., None], Bf)
 
-    hf, ys = jax.lax.scan(per_chunk, h0, xs)
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, S, H, P)
+    # inter-chunk recurrence as one masked (c+1) x (c+1) decay matrix over
+    # [h0, states]: row k is the state entering chunk k, row c the final.
+    tot = jnp.cumsum(jnp.pad(L[..., -1], ((0, 0), (0, 0), (1, 0))), axis=-1)
+    ccausal = jnp.tril(jnp.ones((nc + 1, nc + 1), bool))
+    cdecay = jnp.exp(jnp.where(ccausal, tot[..., :, None] - tot[..., None, :],
+                               -jnp.inf))                  # (b,H,c+1,c+1)
+    carried = jnp.concatenate([h0.astype(f32)[:, :, None], states], axis=2)
+    h_all = jnp.einsum("bhkj,bhjpn->bhkpn", cdecay, carried)
+    h_in, hf = h_all[:, :, :-1], h_all[:, :, -1]
+
+    # incoming state's contribution: y[t] += exp(L[t]) C[t] . h_in
+    y_off = jnp.einsum("bctn,bhcpn->bhctp", Cf, h_in) * jnp.exp(L)[..., None]
+    y = (y + y_off).transpose(0, 2, 3, 1, 4).reshape(b, S, H, P)
     return y.astype(x.dtype), hf
 
 

@@ -138,22 +138,81 @@ def test_ssd_scan_dtypes(dtype):
                                rtol=tol["rtol"] * 10, atol=tol["atol"] * 10)
 
 
-def test_ssd_model_chunked_matches_sequential():
-    """The model's chunked SSD (jnp twin of the kernel) matches the
-    sequential recurrence for several chunk sizes."""
-    from repro.models.ssm import ssd_chunked, ssd_reference
-    key = jax.random.PRNGKey(3)
-    ks = jax.random.split(key, 5)
-    b, S, H, P, N = 2, 192, 3, 16, 8
+def _ssd_inputs(seed=3, b=2, S=192, H=3, P=16, N=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     x = jax.random.normal(ks[0], (b, S, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, S, H)))
     A = -jnp.exp(jax.random.normal(ks[2], (H,)))
     Bm = jax.random.normal(ks[3], (b, S, N))
     Cm = jax.random.normal(ks[4], (b, S, N))
-    y_ref, h_ref = ssd_reference(x, dt, A, Bm, Cm)
-    for chunk in (16, 48, 96, 192):
-        y, h = ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                                   rtol=3e-4, atol=3e-4)
-        np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
-                                   rtol=3e-4, atol=3e-4)
+    h0 = jax.random.normal(ks[5], (b, H, P, N))
+    return x, dt, A, Bm, Cm, h0
+
+
+@pytest.mark.parametrize("h0", ["zeros", "random"])
+@pytest.mark.parametrize("chunk", [16, 48, 96, 192])
+def test_ssd_model_chunked_matches_sequential(chunk, h0):
+    """The model's chunked SSD (all chunks at once) matches the sequential
+    recurrence, from a zero or a carried-in state; chunk 192 is the
+    single-chunk case."""
+    from repro.models.ssm import ssd_chunked, ssd_reference
+    x, dt, A, Bm, Cm, h_init = _ssd_inputs()
+    h_init = None if h0 == "zeros" else h_init
+    y_ref, h_ref = ssd_reference(x, dt, A, Bm, Cm, h0=h_init)
+    y, h = ssd_chunked(x, dt, A, Bm, Cm, h0=h_init, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=3e-4, atol=3e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                               rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("chunk", [16, 48, 192])
+def test_ssd_model_chunked_gradients_match_sequential(chunk):
+    """Gradients of the chunked SSD with respect to x, dt, A, B and C match
+    those through the sequential recurrence, and stay finite for a head
+    whose per-step decay exp(dt*A) underflows to zero."""
+    from repro.models.ssm import ssd_chunked, ssd_reference
+    x, dt, A, Bm, Cm, h0 = _ssd_inputs()
+    A = A.at[0].set(-200.0)
+    gy = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    gh = jax.random.normal(jax.random.PRNGKey(8), h0.shape)
+
+    def loss(f, **kw):
+        def g(*args):
+            y, h = f(*args, h0=h0, **kw)
+            return jnp.sum(y * gy) + jnp.sum(h * gh)
+        return jax.grad(g, argnums=(0, 1, 2, 3, 4))
+
+    grads = loss(ssd_chunked, chunk=chunk)(x, dt, A, Bm, Cm)
+    refs = loss(ssd_reference)(x, dt, A, Bm, Cm)
+    for name, g, r in zip(("x", "dt", "A", "B", "C"), grads, refs):
+        g, r = np.asarray(g), np.asarray(r)
+        assert np.all(np.isfinite(g)), name
+        scale = np.max(np.abs(r))
+        assert np.max(np.abs(g - r)) <= 5e-4 * scale, name
+
+
+def _count_primitive(jaxpr, name):
+    """Equations named ``name`` in ``jaxpr`` and, recursively, in its
+    sub-jaxprs."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    n += _count_primitive(sub, name)
+    return n
+
+
+def test_ssd_stage_program_has_no_chunk_loop():
+    """A Mamba-2 stage's fwd_res holds one scan, the one over layers: the
+    SSD computes its chunks without a sequential loop."""
+    from repro.configs import get_config
+    from repro.core.runtime.stages import init_stage_params, stage_kernels
+    cfg = get_config("mamba2-130m").reduced(num_layers=2, d_model=64)
+    p = init_stage_params(cfg, 0, 1, jax.random.PRNGKey(0))
+    x = jnp.zeros((2, 256, cfg.d_model), jnp.dtype(cfg.param_dtype))
+    jaxpr = jax.make_jaxpr(stage_kernels(cfg, False).fwd_res)(p, x)
+    assert _count_primitive(jaxpr.jaxpr, "scan") == 1
