@@ -15,6 +15,9 @@ whole graph, and B microbatches cost B full-model dispatches.
 * ``backward_from_residuals(s, residuals, g)`` — pulls the cotangent
   ``g`` back through the stored residuals to ``(dparams, dx)``
   *without recomputing the forward*.  This is the default backward.
+  (Inside Mamba layers the SSD runs under ``jax.checkpoint``: its
+  residuals are its inputs, and this backward recomputes its chunk
+  intermediates — see ``repro.models.ssm.ssd_chunked``.)
 * ``forward(s, params, x)`` / ``backward(s, params, x, g)`` — the
   rematerialising pair kept as the in-engine equality oracle:
   ``backward`` re-runs the *same* compiled residual-capturing forward

@@ -1,8 +1,8 @@
 """Mamba2 / SSD (state-space duality) block — arXiv:2405.21060.
 
 Implements the chunked SSD algorithm for all chunks at once: intra-chunk
-quadratic (attention-like) term + inter-chunk recurrence as one small
-masked decay matrix over the chunk axis, with no sequential loop.  A
+quadratic (attention-like) term + inter-chunk recurrence as masked decay
+matmuls over blocks of chunks, with no sequential loop.  A
 single-step decode path maintains (conv_state, ssm_state) caches for O(1)
 per-token decoding — this is what makes ``long_500k`` tractable for the
 ssm/hybrid archs.
@@ -57,7 +57,9 @@ def ssd_chunked(x, dt, A, B, C, h0=None, chunk: int = 64):
     Same signature/semantics as ``ssd_reference`` (float32 internal math).
     Head-major layout ``(b, H, c, Q, .)`` keeps the chunk length Q next to
     P or N in the minor dimensions.  Every contraction has two operands,
-    so no ``(b, H, c, Q, Q, P)`` intermediate can appear.
+    so no ``(b, H, c, Q, Q, P)`` intermediate can appear.  States pass
+    between the c chunks through ``_decay_chain``: O(c) work, one small
+    matmul while c < 64.
 
     Under differentiation the backward pass recomputes the chunks from the
     inputs (``jax.checkpoint``): stored, their ``(b, H, c, Q, Q)``,
@@ -99,20 +101,53 @@ def _ssd_all_chunks(x, dt, A, B, C, h0, chunk):
     tail = jnp.exp(L[..., -1:] - L)
     states = jnp.einsum("bhcsp,bcsn->bhcpn", X * tail[..., None], Bf)
 
-    # inter-chunk recurrence as one masked (c+1) x (c+1) decay matrix over
-    # [h0, states]: row k is the state entering chunk k, row c the final.
-    tot = jnp.cumsum(jnp.pad(L[..., -1], ((0, 0), (0, 0), (1, 0))), axis=-1)
-    ccausal = jnp.tril(jnp.ones((nc + 1, nc + 1), bool))
-    cdecay = jnp.exp(jnp.where(ccausal, tot[..., :, None] - tot[..., None, :],
-                               -jnp.inf))                  # (b,H,c+1,c+1)
+    # inter-chunk recurrence h_k = exp(T_k) h_{k-1} + states_k, T_k the
+    # chunk's total log decay, seeded with h0.  Row k of h_all is the
+    # state entering chunk k, row c the final state.
+    T = jnp.pad(L[..., -1], ((0, 0), (0, 0), (1, 0)))     # (b,H,c+1)
     carried = jnp.concatenate([h0.astype(f32)[:, :, None], states], axis=2)
-    h_all = jnp.einsum("bhkj,bhjpn->bhkpn", cdecay, carried)
+    h_all = _decay_chain(T, carried)
     h_in, hf = h_all[:, :, :-1], h_all[:, :, -1]
 
     # incoming state's contribution: y[t] += exp(L[t]) C[t] . h_in
     y_off = jnp.einsum("bctn,bhcpn->bhctp", Cf, h_in) * jnp.exp(L)[..., None]
     y = (y + y_off).transpose(0, 2, 3, 1, 4).reshape(b, S, H, P)
     return y.astype(x.dtype), hf
+
+
+def _decay_chain(log_a, s, block: int = 64):
+    """Every h_k of h_k = exp(log_a[k]) h_{k-1} + s[k], h_{-1} = 0, with no
+    loop.  log_a: (b, H, n); s: (b, H, n, ...).
+
+    Blocks of ``block`` steps each take one masked (block x block) decay
+    matmul; the states entering the blocks are the same recurrence over
+    the blocks' ends, solved by recursion.  So the work is O(n * block),
+    and n <= block (under 64 chunks in ``ssd_chunked``) is one (n x n)
+    matmul.
+    """
+    b, H, n = log_a.shape
+    m = -(-n // block)
+    g = n if m == 1 else block
+    rest = s.shape[3:]
+    tail = ((0, 0),) * len(rest)
+    la = jnp.pad(log_a, ((0, 0), (0, 0), (0, m * g - n))).reshape(b, H, m, g)
+    s = jnp.pad(s, ((0, 0), (0, 0), (0, m * g - n)) + tail)
+    s = s.reshape((b, H, m, g) + rest)
+    # decay[k, j] = exp(sum_{j<i<=k} la[i]), each sum taken from la itself:
+    # as a difference of two cumulative sums it would lose its precision
+    # after one large decay
+    seg = jnp.cumsum(jnp.where(jnp.tril(jnp.ones((g, g), bool), -1),
+                               la[..., :, None], 0.0), axis=-2)
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((g, g), bool)), seg,
+                              -jnp.inf))                   # (b,H,m,g,g)
+    h = jnp.einsum("bhmkj,bhmj...->bhmk...", decay, s)
+    if m > 1:
+        cum = jnp.cumsum(la, axis=-1)
+        ends = _decay_chain(cum[..., -1], h[:, :, :, -1], block)
+        enter = jnp.pad(ends[:, :, :-1], ((0, 0), (0, 0), (1, 0)) + tail)
+        grow = jnp.exp(cum).reshape((b, H, m, g) + (1,) * len(rest))
+        h = h + grow * enter[:, :, :, None]
+    return h.reshape((b, H, m * g) + rest)[:, :, :n]
 
 
 def ssd_decode_step(h, xt, dtt, A, Bt, Ct):

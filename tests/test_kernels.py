@@ -150,11 +150,12 @@ def _ssd_inputs(seed=3, b=2, S=192, H=3, P=16, N=8):
 
 
 @pytest.mark.parametrize("h0", ["zeros", "random"])
-@pytest.mark.parametrize("chunk", [16, 48, 96, 192])
+@pytest.mark.parametrize("chunk", [2, 16, 48, 96, 192])
 def test_ssd_model_chunked_matches_sequential(chunk, h0):
     """The model's chunked SSD (all chunks at once) matches the sequential
     recurrence, from a zero or a carried-in state; chunk 192 is the
-    single-chunk case."""
+    single-chunk case, chunk 2 passes states across 96 chunks, more than
+    one block of the inter-chunk recurrence."""
     from repro.models.ssm import ssd_chunked, ssd_reference
     x, dt, A, Bm, Cm, h_init = _ssd_inputs()
     h_init = None if h0 == "zeros" else h_init
@@ -166,7 +167,7 @@ def test_ssd_model_chunked_matches_sequential(chunk, h0):
                                rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.parametrize("chunk", [16, 48, 192])
+@pytest.mark.parametrize("chunk", [2, 16, 48, 192])
 def test_ssd_model_chunked_gradients_match_sequential(chunk):
     """Gradients of the chunked SSD with respect to x, dt, A, B and C match
     those through the sequential recurrence, and stay finite for a head
@@ -190,6 +191,25 @@ def test_ssd_model_chunked_gradients_match_sequential(chunk):
         assert np.all(np.isfinite(g)), name
         scale = np.max(np.abs(r))
         assert np.max(np.abs(g - r)) <= 5e-4 * scale, name
+
+
+@pytest.mark.parametrize("block", [2, 4, 64])
+def test_ssd_decay_chain_matches_loop(block):
+    """The inter-chunk recurrence h_k = exp(a_k) h_{k-1} + s_k, solved in
+    blocks with the block ends chained by recursion, matches a plain loop,
+    also where a decay underflows to zero."""
+    from repro.models.ssm import _decay_chain
+    ka, ks = jax.random.split(jax.random.PRNGKey(5))
+    log_a = -jax.nn.softplus(jax.random.normal(ka, (2, 3, 50)))
+    log_a = log_a.at[0, 1, 20].set(-1e4)
+    s = jax.random.normal(ks, (2, 3, 50, 6))
+    h, want = jnp.zeros((2, 3, 6)), []
+    for k in range(50):
+        h = jnp.exp(log_a[..., k])[..., None] * h + s[:, :, k]
+        want.append(h)
+    got = _decay_chain(log_a, s, block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(jnp.stack(want, 2)),
+                               rtol=1e-5, atol=1e-5)
 
 
 def _count_primitive(jaxpr, name):
