@@ -58,19 +58,16 @@ def ssd_chunked(x, dt, A, B, C, h0=None, chunk: int = 64):
     Head-major layout ``(b, H, c, Q, .)`` keeps the chunk length Q next to
     P or N in the minor dimensions.  Every contraction has two operands,
     so no ``(b, H, c, Q, Q, P)`` intermediate can appear.  States pass
-    between the c chunks through ``_decay_chain``: O(c) work, one small
-    matmul while c < 64.
+    between the c chunks through ``_decay_chain``: O(c) work, one
+    (c x c) matmul up to 2048 chunks (128k tokens at chunk 64).
 
     Under differentiation the backward pass recomputes the chunks from the
     inputs (``jax.checkpoint``): stored, their ``(b, H, c, Q, Q)``,
     ``(b, H, c, Q, P)`` and per-chunk state intermediates would take as
     many residual bytes as the rest of the Mamba-2 block, for little compute.
     """
-    b, S, H, P = x.shape
-    N = B.shape[-1]
+    S = x.shape[1]
     assert S % chunk == 0, f"S={S} % chunk={chunk}"
-    if h0 is None:
-        h0 = jnp.zeros((b, H, P, N), jnp.float32)
     return _ssd_all_chunks(x, dt, A, B, C, h0, chunk)
 
 
@@ -102,12 +99,18 @@ def _ssd_all_chunks(x, dt, A, B, C, h0, chunk):
     states = jnp.einsum("bhcsp,bcsn->bhcpn", X * tail[..., None], Bf)
 
     # inter-chunk recurrence h_k = exp(T_k) h_{k-1} + states_k, T_k the
-    # chunk's total log decay, seeded with h0.  Row k of h_all is the
-    # state entering chunk k, row c the final state.
-    T = jnp.pad(L[..., -1], ((0, 0), (0, 0), (1, 0)))     # (b,H,c+1)
-    carried = jnp.concatenate([h0.astype(f32)[:, :, None], states], axis=2)
-    h_all = _decay_chain(T, carried)
-    h_in, hf = h_all[:, :, :-1], h_all[:, :, -1]
+    # chunk's total log decay: from a zero state, then h0 decayed by every
+    # chunk so far.  h_end[k] is the state leaving chunk k.
+    T = L[..., -1]                                         # (b,H,c)
+    h_end = _decay_chain(T, states)
+    if h0 is None:
+        h0 = jnp.zeros((b, H, P, N), f32)
+    else:
+        h0 = h0.astype(f32)
+        h_end = h_end + (jnp.exp(jnp.cumsum(T, axis=-1))[..., None, None]
+                         * h0[:, :, None])
+    h_in = jnp.concatenate([h0[:, :, None], h_end[:, :, :-1]], axis=2)
+    hf = h_end[:, :, -1]
 
     # incoming state's contribution: y[t] += exp(L[t]) C[t] . h_in
     y_off = jnp.einsum("bctn,bhcpn->bhctp", Cf, h_in) * jnp.exp(L)[..., None]
@@ -115,15 +118,17 @@ def _ssd_all_chunks(x, dt, A, B, C, h0, chunk):
     return y.astype(x.dtype), hf
 
 
-def _decay_chain(log_a, s, block: int = 64):
+def _decay_chain(log_a, s, block: int = 2048):
     """Every h_k of h_k = exp(log_a[k]) h_{k-1} + s[k], h_{-1} = 0, with no
     loop.  log_a: (b, H, n); s: (b, H, n, ...).
 
     Blocks of ``block`` steps each take one masked (block x block) decay
     matmul; the states entering the blocks are the same recurrence over
     the blocks' ends, solved by recursion.  So the work is O(n * block),
-    and n <= block (under 64 chunks in ``ssd_chunked``) is one (n x n)
-    matmul.
+    and n <= block is one (n x n) matmul.  A second block level costs
+    extra passes over every state (pad, the block-entry update, the
+    slice); on a TPU v5e those took longer than the larger matmul costs,
+    up to 2048 steps of Mamba-2's (64 x 128) states.
     """
     b, H, n = log_a.shape
     m = -(-n // block)

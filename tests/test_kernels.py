@@ -154,8 +154,7 @@ def _ssd_inputs(seed=3, b=2, S=192, H=3, P=16, N=8):
 def test_ssd_model_chunked_matches_sequential(chunk, h0):
     """The model's chunked SSD (all chunks at once) matches the sequential
     recurrence, from a zero or a carried-in state; chunk 192 is the
-    single-chunk case, chunk 2 passes states across 96 chunks, more than
-    one block of the inter-chunk recurrence."""
+    single-chunk case, chunk 2 passes states across 96 chunks."""
     from repro.models.ssm import ssd_chunked, ssd_reference
     x, dt, A, Bm, Cm, h_init = _ssd_inputs()
     h_init = None if h0 == "zeros" else h_init
