@@ -13,6 +13,13 @@ weights (made from the seed in one jitted call), the data nodes'
 shards and the churn trace, and takes from it only its iteration
 results, its optimizer state after the first step, its parameters after
 the checked steps, and the device trace.
+
+A model family gives its layers in one of two forms, set out in
+``reference.py``: homogeneous, each stage one tree of layer-stacked
+leaves, or kinded (``layer_kinds``), each stage a dict ``{kind: that
+kind's layers in the stage, stacked in layer order}`` with absent kinds
+left out.  The program must hold ``trainer.stage_params[s]`` in that
+same layout, shapes and dtypes; ``give_weights`` refuses it otherwise.
 """
 from __future__ import annotations
 
@@ -264,6 +271,24 @@ def holds(c: dict) -> bool:
 # The run
 # ---------------------------------------------------------------------------
 
+def record(spec, counters, **measured) -> SimpleNamespace:
+    """What the metric readers read: the window's counters and
+    measurements, with the cell's shapes and work counts (``work.py``):
+    ``stage_layers``, and for a kinded family ``stage_kinds``."""
+    from benchmarks.chip.reference import stage_bounds, stage_kinds
+
+    family, model = family_of(spec), spec.config["model"]
+    batch, S = spec.traffic["batch"], spec.traffic["topology"]["stages"]
+    return SimpleNamespace(
+        **vars(counters), **measured,
+        tokens_per_mb=batch["microbatch"] * batch["seq_len"],
+        seq_len=batch["seq_len"], model=model,
+        counts=family.counts(model, batch["seq_len"]),
+        stage_layers=[hi - lo for lo, hi in stage_bounds(
+            model["num_layers"], S)],
+        stage_kinds=stage_kinds(family, model, S), trace={})
+
+
 def run_cell(spec, seed: int, seconds: float, trace: bool, devices,
              t_process: float, peaks: dict, clock: CompileClock,
              trace_dir: str = None) -> dict:
@@ -272,7 +297,7 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, devices,
     import jax
 
     from benchmarks.chip import tracing
-    from benchmarks.chip.reference import readings, stage_bounds
+    from benchmarks.chip.reference import readings
 
     spans = tracing.Spans(trace)
     trainer, shards = build(spec, seed)
@@ -323,18 +348,10 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, devices,
     correct = all(holds(c) for c in checks.values())
 
     # ---- metrics --------------------------------------------------------
-    batch = spec.traffic["batch"]
-    rec = SimpleNamespace(
-        **vars(n), window_s=t1 - t0, setup_s=setup_s,
-        setup_compile_s=clock.setup_s, window_lowered=clock.window_lowered,
-        tokens_per_mb=batch["microbatch"] * batch["seq_len"],
-        seq_len=batch["seq_len"], peak_bytes=peak, chips=len(devices),
-        peaks=peaks, model=spec.config["model"],
-        counts=family_of(spec).counts(spec.config["model"], batch["seq_len"]),
-        stage_layers=[hi - lo for lo, hi in stage_bounds(
-            spec.config["model"]["num_layers"],
-            spec.traffic["topology"]["stages"])],
-        trace={})
+    rec = record(spec, n, window_s=t1 - t0, setup_s=setup_s,
+                 setup_compile_s=clock.setup_s,
+                 window_lowered=clock.window_lowered, peak_bytes=peak,
+                 chips=len(devices), peaks=peaks)
     breakdown = None
     if trace:
         rec.trace = tracing.reduce(tracing.extract(trace_dir))

@@ -25,6 +25,35 @@ leaf).  A leaf's gap is measured against the reference's norm of that
 leaf or of the median leaf, whichever is larger.  Leaves whose first
 reference gradient is under a thousandth of the median leaf's are left
 out of the change.
+
+The family contract.  A model family (``families/<family>.py``) gives
+``init_head(key, model)``, ``embed(head, tokens)``, ``head_loss(head, x,
+labels, model, pr)`` and ``counts(model, seq_len)``, and its layers in
+one of two forms:
+
+- Homogeneous (no ``layer_kinds``): every layer has one tree.
+  ``init_layer(key, model)`` and ``layer(p, x, model, pr)``; ``counts``
+  gives ``layer_flops`` and ``layer_param_bytes`` as numbers.  Stage
+  *s* holds layers ``[lo, hi)`` of ``stage_bounds`` as one tree whose
+  leaves are stacked over those layers, and runs them by one
+  ``lax.scan``.
+- Kinded: ``layer_kinds(model)`` gives one kind name per layer of
+  ``model["num_layers"]``, read from a string key of the model (the
+  harness never parses a pattern).  ``init_layer(key, model, kind)`` and
+  ``layer(p, x, model, pr, kind)`` take the kind; ``counts`` gives
+  ``layer_flops`` and ``layer_param_bytes`` as dicts keyed by kind
+  (``head_flops`` and ``act_bytes`` as numbers).  Stage *s* is a dict
+  ``{kind: tree}`` holding, for each kind with a layer in ``[lo, hi)``,
+  that kind's layers stacked in layer order; a kind with no layer in
+  the stage is absent.  Layer *i* of kind *k* is slice *j* of
+  ``stage[k]``, *j* the number of earlier *k* layers in the stage, and
+  layers run one by one in published order.  Leaf paths read
+  ``stage0/<kind>/...``.
+
+Either way layer *i*'s weights are drawn from ``jax.random.split(kl,
+num_layers)[i]``, so they do not depend on the number of stages, and a
+program that runs the family holds ``stage_params[s]`` in exactly this
+layout (``harness.give_weights`` refuses any other).
 """
 from __future__ import annotations
 
@@ -52,16 +81,57 @@ def stage_bounds(num_layers: int, num_stages: int) -> List[Tuple[int, int]]:
     return out
 
 
+def layer_kinds(family, model: dict):
+    """The kind of each layer, or None for a homogeneous family."""
+    if not hasattr(family, "layer_kinds"):
+        return None
+    kinds = tuple(family.layer_kinds(model))
+    if len(kinds) != model["num_layers"]:
+        raise ValueError(f"{len(kinds)} layer kinds for "
+                         f"{model['num_layers']} layers")
+    return kinds
+
+
+def stage_kinds(family, model: dict, num_stages: int):
+    """Per stage, ``{kind: its layers in the stage}`` in first-seen
+    order; None for a homogeneous family."""
+    kinds = layer_kinds(family, model)
+    if kinds is None:
+        return None
+    return [{k: kinds[lo:hi].count(k) for k in dict.fromkeys(kinds[lo:hi])}
+            for lo, hi in stage_bounds(len(kinds), num_stages)]
+
+
+def _kinded_stages(family, model, kinds, keys, bounds):
+    """Stage dicts of a kinded family: one vmap per kind over its
+    layers' keys, each stage taking its contiguous run of them."""
+    stages = [{} for _ in bounds]
+    for kind in sorted(set(kinds)):
+        idx = [i for i, k in enumerate(kinds) if k == kind]
+        layers = jax.vmap(lambda k, kind=kind: family.init_layer(
+            k, model, kind))(keys[jnp.asarray(idx)])
+        for stage, (lo, hi) in zip(stages, bounds):
+            pos = [j for j, i in enumerate(idx) if lo <= i < hi]
+            if pos:
+                stage[kind] = jax.tree.map(
+                    lambda a, a0=pos[0], a1=pos[-1] + 1: a[a0:a1], layers)
+    return tuple(stages)
+
+
 @functools.lru_cache(maxsize=None)
 def _init_fn(family, model_items: tuple, num_stages: int):
     model = dict(model_items)
     bounds = stage_bounds(model["num_layers"], num_stages)
+    kinds = layer_kinds(family, model)
 
     @jax.jit
     def make(key):
         kl, kh = jax.random.split(key)
-        layers = jax.vmap(lambda k: family.init_layer(k, model))(
-            jax.random.split(kl, model["num_layers"]))
+        keys = jax.random.split(kl, model["num_layers"])
+        if kinds is not None:
+            return (_kinded_stages(family, model, kinds, keys, bounds),
+                    family.init_head(kh, model))
+        layers = jax.vmap(lambda k: family.init_layer(k, model))(keys)
         stages = tuple(jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi], layers)
                        for lo, hi in bounds)
         return stages, family.init_head(kh, model)
@@ -142,11 +212,22 @@ class AdamWRef:
 
 
 def _loss(family, model, pr, stages, head, tokens, labels):
-    layer = jax.checkpoint(lambda h, p: (family.layer(p, h, model, pr),
-                                         None))
     x = family.embed(head, tokens)
-    for sp in stages:
-        x, _ = jax.lax.scan(layer, x, sp)
+    kinds = layer_kinds(family, model)
+    if kinds is None:
+        layer = jax.checkpoint(lambda h, p: (family.layer(p, h, model, pr),
+                                             None))
+        for sp in stages:
+            x, _ = jax.lax.scan(layer, x, sp)
+    else:
+        for sp, (lo, hi) in zip(stages,
+                                stage_bounds(len(kinds), len(stages))):
+            seen = dict.fromkeys(sp, 0)
+            for kind in kinds[lo:hi]:
+                p = jax.tree.map(lambda a, j=seen[kind]: a[j], sp[kind])
+                seen[kind] += 1
+                x = jax.checkpoint(lambda p, h, kind=kind: family.layer(
+                    p, h, model, pr, kind))(p, x)
     return family.head_loss(head, x, labels, model, pr)
 
 

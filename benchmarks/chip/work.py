@@ -6,30 +6,52 @@ Bytes are the lower bound the algorithm must move: each stage's
 parameters read once per iteration (and, for the backward, its
 gradients written once), and each microbatch's boundary activations in
 and out.  Forward repairs run another program and are not counted here;
-each backward replay is one more microbatch-stage of backward work.
+each backward replay is charged the mean stage's backward work, since
+the trace does not tell which stage replayed.
+
+For a family whose layers are all alike, ``rec.counts`` gives one
+layer's FLOPs and parameter bytes and ``rec.stage_layers`` the layers
+of each stage.  For a kinded family (``reference.py``) the counts are
+dicts keyed by kind and ``rec.stage_kinds`` gives each stage's
+``{kind: layers}``; FLOPs and bytes are summed kind by kind.
 """
 from __future__ import annotations
 
 
+def _layer_work(rec):
+    """``(layers, forward FLOPs per token, parameter bytes)`` of each
+    layer kind over all stages; one triple where all layers are alike."""
+    c = rec.counts
+    kinds = getattr(rec, "stage_kinds", None)
+    if kinds is None:
+        return [(sum(rec.stage_layers), c["layer_flops"],
+                 c["layer_param_bytes"])]
+    n = {}
+    for stage in kinds:
+        for k, layers in stage.items():
+            n[k] = n.get(k, 0) + layers
+    return [(n[k], c["layer_flops"][k], c["layer_param_bytes"][k])
+            for k in sorted(n)]
+
+
 def train_flops_per_token(rec) -> float:
     """Forward and backward FLOPs per trained token (3x the forward)."""
-    c = rec.counts
-    return 3.0 * (sum(rec.stage_layers) * c["layer_flops"] + c["head_flops"])
+    layers = sum(n * f for n, f, _ in _layer_work(rec))
+    return 3.0 * (layers + rec.counts["head_flops"])
 
 
 def stage_pass(rec, direction: str):
     """(FLOPs, bytes) of all ``fwd`` or ``bwd`` stage work in the window."""
     c, tok = rec.counts, rec.tokens_per_mb
     S = len(rec.stage_layers)
-    layers = sum(rec.stage_layers)
+    work = _layer_work(rec)
     mb_stages = rec.completed * S
-    per_layer_flops = c["layer_flops"] * tok
-    flops = rec.completed * layers * per_layer_flops
-    params = rec.iterations * layers * c["layer_param_bytes"]
+    flops = sum(rec.completed * n * (f * tok) for n, f, _ in work)
+    params = sum(rec.iterations * n * b for n, _, b in work)
     if direction == "bwd":
         mb_stages += rec.bwd_replays
-        flops = 2.0 * (flops + rec.bwd_replays * layers / S
-                       * per_layer_flops)
+        flops = 2.0 * (flops + sum(rec.bwd_replays * n / S * (f * tok)
+                                   for n, f, _ in work))
         params *= 2
     return flops, params + mb_stages * 2 * tok * c["act_bytes"]
 
