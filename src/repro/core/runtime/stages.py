@@ -28,6 +28,13 @@ whole graph, and B microbatches cost B full-model dispatches.
   stage weights and the upstream activation can (re)produce the
   stage's backward.
 
+A model with a ``layer_pattern`` (layers of different kinds, one mixer
+each: Mamba-2, MoE, attention) holds each stage as ``{kind: that kind's
+layers, stacked}``; its programs take the stage's kinds as a static
+argument and run the layers one by one in published order, each under
+``jax.named_scope("gwtf.<kind>")`` and ``jax.checkpoint``.  Models
+without a pattern keep the one ``lax.scan`` over stacked blocks.
+
 Microbatches of the same stage are stacked along the batch axis, so B
 microbatches cost one dispatch per stage instead of B full-model
 dispatches.  Cotangents are donated to the backward dispatch on
@@ -55,7 +62,8 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.config import ModelConfig
-from repro.models.transformer import _apply_block, _init_block
+from repro.models.transformer import (_apply_block, _apply_layer,
+                                      _init_block, _init_layer, layer_kinds)
 
 
 # ---------------------------------------------------------------------------
@@ -70,16 +78,48 @@ def stage_bounds(cfg: ModelConfig, stage: int, num_stages: int):
     return lo, hi
 
 
+def stage_kinds(cfg: ModelConfig, stage: int, num_stages: int):
+    """The kinds of the stage's layers in published order, or None where
+    the model has no ``layer_pattern`` (every layer one block)."""
+    if not cfg.layer_pattern:
+        return None
+    lo, hi = stage_bounds(cfg, stage, num_stages)
+    return tuple(layer_kinds(cfg)[lo:hi])
+
+
 def init_stage_params(cfg: ModelConfig, stage: int, num_stages: int, key):
-    """Blocks [lo, hi) of the model as one stage (stacked for scan)."""
+    """Blocks [lo, hi) of the model as one stage (stacked for scan).  With
+    a ``layer_pattern``: ``{kind: that kind's layers in the stage, stacked
+    in layer order}``, a kind with no layer in the stage absent."""
     lo, hi = stage_bounds(cfg, stage, num_stages)
     keys = jax.random.split(jax.random.fold_in(key, stage), hi - lo)
     dtype = jnp.dtype(cfg.param_dtype)
-    return jax.vmap(lambda kk: _init_block(kk, cfg, dtype))(keys)
+    kinds = stage_kinds(cfg, stage, num_stages)
+    if kinds is None:
+        return jax.vmap(lambda kk: _init_block(kk, cfg, dtype))(keys)
+    return {kind: jax.vmap(lambda kk, kind=kind: _init_layer(
+        kk, cfg, kind, dtype))(keys[jnp.asarray(
+            [i for i, k in enumerate(kinds) if k == kind])])
+        for kind in dict.fromkeys(kinds)}
 
 
-def stage_forward(stage_params, x, cfg: ModelConfig):
+def stage_forward(stage_params, x, cfg: ModelConfig, kinds=None):
+    """The stage's layers over ``x``.  ``kinds`` (``stage_kinds``) gives a
+    patterned stage's layer order: layer j of a kind is slice j of that
+    kind's stack, and each runs under the scope ``gwtf.<kind>`` and
+    ``jax.checkpoint``, so that its residuals are its inputs and the
+    backward recomputes the rest (a Mamba-2 and MoE stage would otherwise
+    store about 1.5 GiB of residuals per 4 x 512 tokens)."""
     positions = jnp.arange(x.shape[1])
+    if kinds is not None:
+        seen = dict.fromkeys(stage_params, 0)
+        for kind in kinds:
+            p = jax.tree.map(lambda a, j=seen[kind]: a[j], stage_params[kind])
+            seen[kind] += 1
+            with jax.named_scope(f"gwtf.{kind}"):
+                x = jax.checkpoint(lambda p, x, kind=kind: _apply_layer(
+                    p, x, cfg, kind, positions=positions))(p, x)
+        return x
 
     def body(carry, bp):
         h, _aux, _ = _apply_block(bp, carry, cfg, positions=positions,
@@ -136,20 +176,21 @@ def stage_kernels(cfg: ModelConfig, donate: bool) -> StageKernels:
     stage and every stage count; the cache key is the hashable frozen
     ``ModelConfig`` plus the donation flag.
     """
-    def fwd_impl(p, x):
-        return stage_forward(p, x, cfg)
+    def fwd_impl(p, x, kinds=None):
+        return stage_forward(p, x, cfg, kinds)
 
-    fwd = jax.jit(fwd_impl)
+    fwd = jax.jit(fwd_impl, static_argnums=2)
 
-    def fwd_res_impl(p, x):
+    def fwd_res_impl(p, x, kinds=None):
         # jax.vjp inside jit: the returned closure is a
         # jax.tree_util.Partial whose leaves are the residual arrays —
         # it round-trips the jit boundary as a pytree and can be fed
         # to bwd_res (possibly quantized in between).
-        out, vjp = jax.vjp(lambda pp, xx: stage_forward(pp, xx, cfg), p, x)
+        out, vjp = jax.vjp(lambda pp, xx: stage_forward(pp, xx, cfg, kinds),
+                           p, x)
         return out, vjp
 
-    fwd_res = jax.jit(fwd_res_impl)
+    fwd_res = jax.jit(fwd_res_impl, static_argnums=2)
 
     def bwd_res_impl(vjp, g):
         dp, dx = vjp(g)
@@ -212,6 +253,10 @@ class StageCompute:
         self.embed_bwd_calls = 0
         self.head_calls = 0
         self._k = stage_kernels(cfg, self.donate)
+        # each stage's kinds (None without a pattern), a static argument
+        # of its programs
+        self._kinds = [stage_kinds(cfg, s, num_stages)
+                       for s in range(num_stages)]
 
     # ------------------------------------------------------------------
     def embed(self, head_params, tokens):
@@ -228,7 +273,7 @@ class StageCompute:
         """One plain dispatch of stage ``stage`` over a stacked batch
         (no residual capture — the remat path and forward repairs)."""
         self.fwd_calls[stage] += 1
-        return self._k.fwd(params, x)
+        return self._k.fwd(params, x, self._kinds[stage])
 
     def forward_fused(self, stage: int, params, x) -> Tuple[Any, Any]:
         """One fused dispatch: ``(output, residuals)``.  The output is
@@ -236,7 +281,7 @@ class StageCompute:
         ``jax.tree_util.Partial``) feed :meth:`backward_from_residuals`
         so the backward never re-runs the forward."""
         self.fwd_calls[stage] += 1
-        return self._k.fwd_res(params, x)
+        return self._k.fwd_res(params, x, self._kinds[stage])
 
     def backward_from_residuals(self, stage: int, residuals, g
                                 ) -> Tuple[Any, Any]:
@@ -257,7 +302,7 @@ class StageCompute:
         """
         self.bwd_calls[stage] += 1
         self.remat_recomputes[stage] += 1
-        _, vjp = self._k.fwd_res(params, x)
+        _, vjp = self._k.fwd_res(params, x, self._kinds[stage])
         return self._k.bwd_res(vjp, g)
 
     def head_loss(self, head_params, hidden, labels):

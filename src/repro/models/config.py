@@ -27,24 +27,39 @@ class ModelConfig:
     # --- attention ---
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope: bool = True                   # False: no rotary positions at all
     sliding_window: Optional[int] = None  # decode-time SWA window (long_500k)
 
     # --- mlp / norm ---
-    mlp_type: str = "swiglu"            # swiglu | geglu | gelu
+    mlp_type: str = "swiglu"            # swiglu | geglu | gelu | relu2 (no gate)
     norm_type: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6
+
+    # --- layers of different kinds, one mixer each (nemotron_h) ---
+    # One character per layer: M Mamba-2, E MoE, * attention.  Empty:
+    # every layer is the arch_type's block.
+    layer_pattern: str = ""
 
     # --- ssm (mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_conv: int = 4
-    ssm_expand: int = 2
+    ssm_expand: int = 2                 # 0: inner width ssm_heads * ssm_head_dim
+    ssm_groups: int = 1                 # B/C groups; head h reads group h // (H / G)
 
     # --- moe ---
     num_experts: int = 0
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0         # qwen2-moe style shared expert(s)
+    shared_d_ff: int = 0                # shared-expert width (0: d_ff * num_shared_experts)
     router_aux_coef: float = 0.01       # load-balance loss coefficient
+    router: str = "softmax"             # softmax | sigmoid (top-k scores renormalised)
+    routed_scaling: float = 1.0         # combine weights times this (sigmoid router)
+    # The experts this layer holds: [first_expert, first_expert +
+    # experts_held) of the num_experts routed over (0: all of them).
+    experts_held: int = 0
+    first_expert: int = 0
 
     # --- vlm (cross-attention image layers) ---
     cross_attn_every: int = 0           # every k-th layer is cross-attn (0 = none)
@@ -72,7 +87,17 @@ class ModelConfig:
     @property
     def d_inner(self) -> int:
         """SSM inner width."""
+        if not self.ssm_expand:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
+
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_d_ff or self.d_ff * self.num_shared_experts
 
     @property
     def has_attention(self) -> bool:
@@ -113,6 +138,11 @@ class ModelConfig:
             num_experts=experts,
             num_experts_per_tok=topk,
             num_shared_experts=min(self.num_shared_experts, 1),
+            shared_d_ff=max(64, int(self.shared_d_ff * scale)) if self.shared_d_ff else 0,
+            experts_held=min(self.experts_held, experts),
+            first_expert=0,
+            ssm_groups=min(self.ssm_groups, self.ssm_heads, 4) if self.ssm_heads else 1,
+            layer_pattern=(self.layer_pattern * num_layers)[:num_layers],
             cross_attn_every=min(self.cross_attn_every, num_layers) if self.cross_attn_every else 0,
             num_image_tokens=min(self.num_image_tokens, 16),
             vision_dim=min(self.vision_dim, 128) if self.vision_dim else 0,
@@ -122,6 +152,10 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (embeddings included)."""
+        if self.layer_pattern:
+            raise NotImplementedError(
+                "param_count prices every layer alike; a layer_pattern "
+                "model's layers differ")
         D, F, V, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         per_layer = 0
         if self.has_attention:

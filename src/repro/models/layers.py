@@ -38,7 +38,8 @@ def init_norm(cfg: ModelConfig, dim: Optional[int] = None):
     return p
 
 
-def apply_norm(p, x, cfg: ModelConfig, eps: float = 1e-6):
+def apply_norm(p, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm_type == "layernorm":
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -293,7 +294,7 @@ def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
 
     q = q.reshape(B, S, H, hd)
-    if kv_x is None:
+    if kv_x is None and cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k.reshape(B, -1, KH, hd), positions, cfg.rope_theta)
         k = k.reshape(B, -1, cfg.kv_dim)

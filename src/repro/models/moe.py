@@ -12,7 +12,13 @@ Two execution modes:
 
 Router load-balance auxiliary loss (Switch-style) is returned so training
 can keep experts balanced — GWTF's bottleneck-stage argument applied to
-experts.
+experts.  The sigmoid router (nemotron_h) balances without one and
+returns 0.
+
+A layer may hold a share of the experts (``experts_held`` from
+``first_expert``), as one chip of an expert-parallel deployment does: it
+routes over all ``num_experts`` and, on the ``ragged`` path, computes
+only its own experts' part of the result; the shared expert is whole.
 """
 from __future__ import annotations
 
@@ -25,35 +31,59 @@ from repro.models.config import ModelConfig
 from repro.models.layers import dense_init
 
 
+def _gated(cfg: ModelConfig) -> bool:
+    return cfg.mlp_type in ("swiglu", "geglu")
+
+
+def _act(g, cfg: ModelConfig):
+    if cfg.mlp_type == "swiglu":
+        return jax.nn.silu(g)
+    if cfg.mlp_type == "relu2":
+        return jnp.square(jax.nn.relu(g))
+    return jax.nn.gelu(g)
+
+
 def init_moe(key, cfg: ModelConfig, dtype):
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    """Router over all ``num_experts``; weights of the experts held."""
+    D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts_held
     ks = jax.random.split(key, 5)
-    p = {
-        "router": dense_init(ks[0], (D, E), jnp.float32, scale=0.02),
-        "w_gate": dense_init(ks[1], (E, D, F), dtype),
-        "w_up": dense_init(ks[2], (E, D, F), dtype),
-        "w_down": dense_init(ks[3], (E, F, D), dtype),
-    }
+    p = {"router": dense_init(ks[0], (D, cfg.num_experts), jnp.float32,
+                              scale=0.02)}
+    if _gated(cfg):
+        p["w_gate"] = dense_init(ks[1], (E, D, F), dtype)
+    p["w_up"] = dense_init(ks[2], (E, D, F), dtype)
+    p["w_down"] = dense_init(ks[3], (E, F, D), dtype)
     if cfg.num_shared_experts:
-        Fs = F * cfg.num_shared_experts
+        Fs = cfg.shared_width
         sk = jax.random.split(ks[4], 3)
-        p["shared"] = {
-            "w_gate": dense_init(sk[0], (D, Fs), dtype),
-            "w_up": dense_init(sk[1], (D, Fs), dtype),
-            "w_down": dense_init(sk[2], (Fs, D), dtype),
-        }
+        p["shared"] = {}
+        if _gated(cfg):
+            p["shared"]["w_gate"] = dense_init(sk[0], (D, Fs), dtype)
+        p["shared"]["w_up"] = dense_init(sk[1], (D, Fs), dtype)
+        p["shared"]["w_down"] = dense_init(sk[2], (Fs, D), dtype)
     return p
 
 
 def _route(p, x, cfg: ModelConfig):
     """Returns (weights (T,E) combine weights, aux_loss). x: (T, D)."""
-    logits = x.astype(jnp.float32) @ p["router"]          # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
     k = cfg.num_experts_per_tok
-    topv, topi = jax.lax.top_k(probs, k)                  # (T, k)
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)   # renormalise
+    if cfg.router == "sigmoid":
+        # nemotron_h: float32 scores, the top k chosen (its score-correction
+        # bias is zero here), their scores renormalised and scaled; no aux
+        probs = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["router"],
+                                       precision=jax.lax.Precision.HIGHEST))
+        topv, topi = jax.lax.top_k(probs, k)
+        topv = (topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+                * cfg.routed_scaling)
+    else:
+        logits = x.astype(jnp.float32) @ p["router"]      # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, k)              # (T, k)
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)   # renormalise
     combine = jnp.zeros_like(probs).at[
         jnp.arange(x.shape[0])[:, None], topi].set(topv)  # (T, E)
+    if cfg.router == "sigmoid":
+        return combine, topi, topv, jnp.float32(0.0)
     # Switch aux loss: E * sum_e (frac_tokens_e * mean_prob_e)
     frac = jnp.mean((combine > 0).astype(jnp.float32), axis=0)
     aux = cfg.num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
@@ -74,40 +104,55 @@ def _expert_mlp_dense(p, x, combine, cfg: ModelConfig):
     from repro.parallel.sharding import shard
 
     def one_expert(acc, ewc):
-        wg, wu, wd, c_e = ewc                  # (D,F), (D,F), (F,D), (T,)
-        g = shard(x @ wg, "batch", "tp")
-        act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
-        h = act * (x @ wu)                     # (T, F)
+        ew, c_e = ewc                          # {w_gate?, w_up, w_down}, (T,)
+        if _gated(cfg):
+            g = shard(x @ ew["w_gate"], "batch", "tp")
+            h = _act(g, cfg) * (x @ ew["w_up"])    # (T, F)
+        else:
+            h = _act(shard(x @ ew["w_up"], "batch", "tp"), cfg)
         h = h * c_e[:, None].astype(h.dtype)
-        return acc + h @ wd, None
+        return acc + h @ ew["w_down"], None
 
     acc0 = jnp.zeros_like(x)
-    out, _ = jax.lax.scan(
-        one_expert, acc0,
-        (p["w_gate"], p["w_up"], p["w_down"], combine.T.astype(x.dtype)))
+    experts = {k: p[k] for k in ("w_gate", "w_up", "w_down") if k in p}
+    out, _ = jax.lax.scan(one_expert, acc0,
+                          (experts, combine.T.astype(x.dtype)))
     return out
 
 
 def _expert_mlp_ragged(p, x, topi, topv, cfg: ModelConfig):
     """Active-only path: sort (token, expert) pairs by expert, ragged_dot.
 
-    FLOPs ~ T*topk*D*F instead of T*E*D*F.
+    Only the pairs of the experts held, ``[first_expert, first_expert +
+    experts_held)``, form groups; every other pair sorts after them and
+    its rows are zero, so what the absent experts would add is left out.
+    FLOPs ~ held pairs * D * F instead of T*E*D*F.
     """
     T, D = x.shape
-    k = cfg.num_experts_per_tok
-    E = cfg.num_experts
-    flat_e = topi.reshape(-1)                              # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(T), k)                  # (T*k,)
-    flat_w = topv.reshape(-1)
-    order = jnp.argsort(flat_e)                            # stable sort by expert
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    xs = x[st]                                             # (T*k, D) gathered
-    group_sizes = jnp.bincount(se, length=E).astype(jnp.int32)
-    g = jax.lax.ragged_dot(xs, p["w_gate"], group_sizes)
-    u = jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
-    act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
-    y = jax.lax.ragged_dot((act * u).astype(xs.dtype), p["w_down"], group_sizes)
-    y = y * sw[:, None].astype(y.dtype)
+    k = topi.shape[1]
+    n = cfg.num_experts_held
+    e = topi.reshape(-1) - cfg.first_expert                # (T*k,)
+    e = jnp.where((e >= 0) & (e < n), e, n)
+    order = jnp.argsort(e, stable=True)                    # held first, by expert
+    se, st, sw = e[order], order // k, topv.reshape(-1)[order]
+    # the token index by division and the group sizes by compare-and-sum:
+    # on a TPU v5e a gathered index and bincount's scatter-add made the
+    # Nemotron stage programs 6 % slower
+    group_sizes = jnp.sum(se[None, :] == jnp.arange(n)[:, None], axis=1,
+                          dtype=jnp.int32)
+    held = (se < n)[:, None]
+
+    def dot(a, w):
+        # ragged_dot leaves the rows past the groups undefined (on a TPU,
+        # whatever the buffer held): zero them here, so that no operand of
+        # either pass, the backward's products with zero cotangents
+        # included, reads them
+        return jnp.where(held, jax.lax.ragged_dot(a, w, group_sizes), 0)
+
+    xs = jnp.where(held, x[st], 0)                         # (T*k, D) gathered
+    u = dot(xs, p["w_up"])
+    h = _act(dot(xs, p["w_gate"]), cfg) * u if _gated(cfg) else _act(u, cfg)
+    y = dot(h.astype(xs.dtype), p["w_down"]) * sw[:, None].astype(x.dtype)
     return jnp.zeros_like(x).at[st].add(y)
 
 
@@ -140,10 +185,13 @@ def _expert_mlp_capacity(p, x, topi, topv, cfg: ModelConfig,
     wk = jnp.where(keep, sw, 0.0)
     buf = jnp.zeros((E, C, D), x.dtype).at[se, pos].set(
         jnp.where(keep[:, None], x[st], 0))
-    g = shard(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"]),
-              None, None, "tp")
-    act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
-    h = act * jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
+    u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
+    if _gated(cfg):
+        g = shard(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"]),
+                  None, None, "tp")
+        h = _act(g, cfg) * u
+    else:
+        h = _act(shard(u, None, None, "tp"), cfg)
     y = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # (E, C, D)
     out = jnp.zeros_like(x).at[st].add(
         y[se, pos] * wk[:, None].astype(y.dtype))
@@ -165,13 +213,16 @@ def apply_moe(p, x, cfg: ModelConfig, impl: str = "dense"):
     combine, topi, topv, aux = _route(p, xt, cfg)
     if impl == "ragged":
         out = _expert_mlp_ragged(p, xt, topi, topv, cfg)
+    elif cfg.num_experts_held < cfg.num_experts:
+        raise ValueError(f"a share of the experts runs only with impl="
+                         f"'ragged', not {impl!r}")
     elif impl == "capacity":
         out = _expert_mlp_capacity(p, xt, topi, topv, cfg)
     else:
         out = _expert_mlp_dense(p, xt, combine, cfg)
     if cfg.num_shared_experts:
         sp = p["shared"]
-        g = xt @ sp["w_gate"]
-        act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
-        out = out + (act * (xt @ sp["w_up"])) @ sp["w_down"]
+        u = xt @ sp["w_up"]
+        h = _act(xt @ sp["w_gate"], cfg) * u if _gated(cfg) else _act(u, cfg)
+        out = out + h @ sp["w_down"]
     return out.reshape(B, S, D), aux

@@ -54,7 +54,9 @@ def ssd_reference(x, dt, A, B, C, h0=None):
 def ssd_chunked(x, dt, A, B, C, h0=None, chunk: int = 64):
     """Chunked SSD, every chunk at once (arXiv:2405.21060, Sec. 6).
 
-    Same signature/semantics as ``ssd_reference`` (float32 internal math).
+    Same signature/semantics as ``ssd_reference`` (float32 internal math);
+    B and C may also come in G groups, (b, S, G, N), head h reading group
+    h // (H / G): each group is then its own SSD over its heads, vmapped.
     Head-major layout ``(b, H, c, Q, .)`` keeps the chunk length Q next to
     P or N in the minor dimensions.  Every contraction has two operands,
     so no ``(b, H, c, Q, Q, P)`` intermediate can appear.  States pass
@@ -66,9 +68,20 @@ def ssd_chunked(x, dt, A, B, C, h0=None, chunk: int = 64):
     ``(b, H, c, Q, P)`` and per-chunk state intermediates would take as
     many residual bytes as the rest of the Mamba-2 block, for little compute.
     """
-    S = x.shape[1]
+    b, S, H, P = x.shape
     assert S % chunk == 0, f"S={S} % chunk={chunk}"
-    return _ssd_all_chunks(x, dt, A, B, C, h0, chunk)
+    if B.ndim == 3:
+        return _ssd_all_chunks(x, dt, A, B, C, h0, chunk)
+    G, N = B.shape[2:]
+
+    def split(a, axis):                      # heads -> (G, H // G)
+        return a.reshape(a.shape[:axis] + (G, H // G) + a.shape[axis + 1:])
+
+    y, hf = jax.vmap(lambda *a: _ssd_all_chunks(*a, chunk),
+                     (2, 2, 0, 2, 2, 1), (2, 1))(
+        split(x, 2), split(dt, 2), split(A, 0), B, C,
+        None if h0 is None else split(h0, 1))
+    return y.reshape(b, S, H, P), hf.reshape(b, H, P, N)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(6,))
@@ -171,11 +184,11 @@ def init_mamba(key, cfg: ModelConfig, dtype):
     D = cfg.d_model
     di = cfg.d_inner
     N, H = cfg.ssm_state, cfg.ssm_heads
-    P = di // H
-    conv_dim = di + 2 * N
+    GN = cfg.ssm_groups * N
+    conv_dim = di + 2 * GN
     ks = jax.random.split(key, 4)
     return {
-        "in_proj": dense_init(ks[0], (D, 2 * di + 2 * N + H), dtype),
+        "in_proj": dense_init(ks[0], (D, 2 * di + 2 * GN + H), dtype),
         "conv_w": (jax.random.normal(ks[1], (cfg.ssm_conv, conv_dim), jnp.float32)
                    * (cfg.ssm_conv ** -0.5)).astype(dtype),
         "conv_b": jnp.zeros((conv_dim,), dtype),
@@ -204,24 +217,29 @@ def apply_mamba(p, x, cfg: ModelConfig, *, cache=None, chunk: int = 64):
     """x: (B, S, D). cache: dict(conv=(B,K-1,conv_dim), ssm=(B,H,P,N)) or None.
     Returns (out, new_cache)."""
     B_, S, D = x.shape
-    di, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, N, H, G = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_groups
     P = di // H
+    GN = G * N
 
     zxbcdt = x @ p["in_proj"]
     z, xs, Bc, Cc, dt = jnp.split(
-        zxbcdt, [di, 2 * di, 2 * di + N, 2 * di + 2 * N], axis=-1)
+        zxbcdt, [di, 2 * di, 2 * di + GN, 2 * di + 2 * GN], axis=-1)
 
     conv_in = jnp.concatenate([xs, Bc, Cc], axis=-1)
     conv_state = cache["conv"] if cache is not None else None
     conv_out, new_conv = _causal_conv(conv_in, p["conv_w"], p["conv_b"], conv_state)
     conv_out = jax.nn.silu(conv_out)
-    xs, Bc, Cc = jnp.split(conv_out, [di, di + N], axis=-1)
+    xs, Bc, Cc = jnp.split(conv_out, [di, di + GN], axis=-1)
+    if G > 1:
+        Bc, Cc = (a.reshape(B_, S, G, N) for a in (Bc, Cc))
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])     # (B,S,H)
     A = -jnp.exp(p["A_log"])                                        # (H,)
     xh = xs.reshape(B_, S, H, P)
 
     if cache is not None and S == 1:
+        if G > 1:
+            raise NotImplementedError("one-token decode reads one B/C group")
         h, y = ssd_decode_step(cache["ssm"], xh[:, 0].astype(jnp.float32),
                                dt[:, 0], A, Bc[:, 0].astype(jnp.float32),
                                Cc[:, 0].astype(jnp.float32))
@@ -235,17 +253,19 @@ def apply_mamba(p, x, cfg: ModelConfig, *, cache=None, chunk: int = 64):
 
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B_, S, di)
-    # gated RMSNorm (mamba2 style)
+    # gated RMSNorm (mamba2 style), over each of the G groups' channels
     g = y * jax.nn.silu(z.astype(jnp.float32))
+    if G > 1:
+        g = g.reshape(B_, S, G, di // G)
     ms = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    g = g * jax.lax.rsqrt(ms + 1e-6) * p["norm_scale"]
+    g = (g * jax.lax.rsqrt(ms + cfg.norm_eps)).reshape(B_, S, di) * p["norm_scale"]
     return g.astype(x.dtype) @ p["out_proj"], new_cache
 
 
 def init_mamba_cache(cfg: ModelConfig, batch: int, dtype=jnp.float32):
     di, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     P = di // H
-    conv_dim = di + 2 * N
+    conv_dim = di + 2 * cfg.ssm_groups * N
     return {
         "conv": jnp.zeros((batch, cfg.ssm_conv - 1, conv_dim), dtype),
         "ssm": jnp.zeros((batch, H, P, N), jnp.float32),

@@ -54,6 +54,39 @@ def _init_block(key, cfg: ModelConfig, dtype):
     return p
 
 
+# Layers of a ``layer_pattern`` (nemotron_h): one pre-norm and one mixer.
+KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+
+
+def layer_kinds(cfg: ModelConfig):
+    """The kind of each layer of ``cfg.layer_pattern``."""
+    return [KINDS[c] for c in cfg.layer_pattern]
+
+
+def _init_layer(key, cfg: ModelConfig, kind: str, dtype):
+    p: Dict[str, Any] = {"ln1": L.init_norm(cfg)}
+    if kind == "mamba":
+        p["mamba"] = SSM.init_mamba(key, cfg, dtype)
+    elif kind == "moe":
+        p["moe"] = MOE.init_moe(key, cfg, dtype)
+    else:
+        p["attn"] = L.init_attention(key, cfg, dtype)
+    return p
+
+
+def _apply_layer(p, x, cfg: ModelConfig, kind: str, *, positions):
+    """``x + mixer(norm(x))``; the MoE layer's routed part on the ragged
+    path, over the experts the layer holds."""
+    h = L.apply_norm(p["ln1"], x, cfg)
+    if kind == "mamba":
+        out, _ = SSM.apply_mamba(p["mamba"], h, cfg)
+    elif kind == "moe":
+        out, _ = MOE.apply_moe(p["moe"], h, cfg, impl="ragged")
+    else:
+        out, _ = L.apply_attention(p["attn"], h, cfg, positions=positions)
+    return x + out
+
+
 def _init_cross_block(key, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 2)
     return {
@@ -67,6 +100,9 @@ def _init_cross_block(key, cfg: ModelConfig, dtype):
 
 
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
+    if cfg.layer_pattern:
+        raise NotImplementedError("a layer pattern runs on the staged "
+                                  "runtime only (core/runtime/stages.py)")
     dtype = jnp.dtype(cfg.param_dtype)
     k_embed, k_blocks, k_cross, k_proj = jax.random.split(key, 4)
     params: Dict[str, Any] = {"embed": L.init_embed(k_embed, cfg, dtype),
