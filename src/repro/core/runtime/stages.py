@@ -35,6 +35,20 @@ argument and run the layers one by one in published order, each under
 ``jax.named_scope("gwtf.<kind>")`` and ``jax.checkpoint``.  Models
 without a pattern keep the one ``lax.scan`` over stacked blocks.
 
+Attention: where the programs are lowered for a TPU, every causal
+self-attention layer of both paths (no cache, no window, a sequence the
+kernel tiles) runs through the fused flash kernel
+(``repro.kernels.ops.fused_attention``), whose VJP saves the output and
+the per-row softmax statistics and recomputes the scores block by block
+in VMEM: ``fwd_res`` stores nothing of size S x S, and ``bwd_res``
+writes none.  Elsewhere, the CPU included, the layers keep the XLA
+attention (``_online_attention``) and the programs are unchanged.  The
+choice is made in Python while the program is traced
+(``layers.fused_attention_applies``), not by ``lax.platform_dependent``
+or ``lax.cond``: under ``jax.vjp`` those keep the residuals of every
+branch, the XLA branch's S x S arrays among them.  ``snapshot()``'s
+``fused_attention`` counts, per stage, the layers that took the kernel.
+
 Microbatches of the same stage are stacked along the batch axis, so B
 microbatches cost one dispatch per stage instead of B full-model
 dispatches.  Cotangents are donated to the backward dispatch on
@@ -85,6 +99,20 @@ def stage_kinds(cfg: ModelConfig, stage: int, num_stages: int):
         return None
     lo, hi = stage_bounds(cfg, stage, num_stages)
     return tuple(layer_kinds(cfg)[lo:hi])
+
+
+def stage_fused_attention(cfg: ModelConfig, stage: int, num_stages: int,
+                          seq_len: int) -> int:
+    """How many of the stage's attention layers its programs run through
+    the fused kernel over ``seq_len`` tokens (``L.fused_attention_applies``):
+    all of them or none."""
+    kinds = stage_kinds(cfg, stage, num_stages)
+    if kinds is None:
+        lo, hi = stage_bounds(cfg, stage, num_stages)
+        n = 0 if cfg.arch_type == "ssm" else hi - lo
+    else:
+        n = kinds.count("attention")
+    return n if n and L.fused_attention_applies(seq_len, cfg.head_dim) else 0
 
 
 def init_stage_params(cfg: ModelConfig, stage: int, num_stages: int, key):
@@ -252,6 +280,8 @@ class StageCompute:
         self.embed_calls = 0
         self.embed_bwd_calls = 0
         self.head_calls = 0
+        # tokens per sequence of each stage's last dispatch (0: none yet)
+        self._seq_len: List[int] = [0] * num_stages
         self._k = stage_kernels(cfg, self.donate)
         # each stage's kinds (None without a pattern), a static argument
         # of its programs
@@ -273,6 +303,7 @@ class StageCompute:
         """One plain dispatch of stage ``stage`` over a stacked batch
         (no residual capture — the remat path and forward repairs)."""
         self.fwd_calls[stage] += 1
+        self._seq_len[stage] = x.shape[1]
         return self._k.fwd(params, x, self._kinds[stage])
 
     def forward_fused(self, stage: int, params, x) -> Tuple[Any, Any]:
@@ -281,6 +312,7 @@ class StageCompute:
         ``jax.tree_util.Partial``) feed :meth:`backward_from_residuals`
         so the backward never re-runs the forward."""
         self.fwd_calls[stage] += 1
+        self._seq_len[stage] = x.shape[1]
         return self._k.fwd_res(params, x, self._kinds[stage])
 
     def backward_from_residuals(self, stage: int, residuals, g
@@ -302,6 +334,7 @@ class StageCompute:
         """
         self.bwd_calls[stage] += 1
         self.remat_recomputes[stage] += 1
+        self._seq_len[stage] = x.shape[1]
         _, vjp = self._k.fwd_res(params, x, self._kinds[stage])
         return self._k.bwd_res(vjp, g)
 
@@ -324,7 +357,12 @@ class StageCompute:
         return sum(self.remat_recomputes)
 
     def snapshot(self) -> Dict[str, Any]:
+        """Dispatch counts, and per stage the attention layers its last
+        dispatched programs ran through the fused kernel
+        (``fused_attention``; 0 before any dispatch)."""
+        fused = [stage_fused_attention(self.cfg, s, self.num_stages, n)
+                 if n else 0 for s, n in enumerate(self._seq_len)]
         return dict(fwd=list(self.fwd_calls), bwd=list(self.bwd_calls),
                     remat=list(self.remat_recomputes),
                     embed=self.embed_calls, embed_bwd=self.embed_bwd_calls,
-                    head=self.head_calls)
+                    head=self.head_calls, fused_attention=fused)

@@ -131,6 +131,14 @@ def _online_attention(q, k, v, q_offset, causal: bool, window: Optional[int],
     q_offset: absolute position of q[0] (int or traced scalar).
     kv_len_valid: optional scalar — number of valid KV entries (cache decode).
     Memory per block: B*H*q_block*Sk — bounded, never S^2.
+
+    Differentiated, it keeps each block's scores, softmax and mask as
+    residuals: S x S per head once Sq <= q_block.  So on a TPU, causal
+    self-attention in training takes the fused kernel instead
+    (``fused_attention_applies``, checked in Python at trace time, since
+    a ``lax.platform_dependent`` branch would keep these residuals too);
+    this path serves the CPU, caches, windows, cross-attention and
+    shapes the kernel does not tile.
     """
     B, Sq, H, hd = q.shape
     _, Sk, KH, _ = k.shape
@@ -262,6 +270,23 @@ def _constrain_attention_operands(q, k, v, H, KH):
     return q, k, v
 
 
+def fused_attention_applies(seq_len: int, head_dim: int) -> bool:
+    """Whether causal self-attention without a cache or window over
+    ``seq_len`` tokens takes the fused kernel (``kops.fused_attention``).
+
+    Decided in Python while the program is traced, from what the trace
+    can observe: the program is lowered for a TPU, no sharding mesh is
+    active (GSPMD does not partition a Pallas kernel), and the kernel
+    takes the shape.  A ``lax.platform_dependent`` or ``lax.cond`` would
+    not do: under ``jax.vjp`` their partial evaluation keeps the
+    residuals of every branch, the XLA branch's S x S arrays among them.
+    """
+    from repro.kernels import ops as kops
+    from repro.parallel.sharding import active_mesh
+    return (kops.on_tpu() and active_mesh() is None
+            and kops.fused_attention_blocks(seq_len, head_dim) is not None)
+
+
 def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
                     window=None, kv_x=None, cache=None, write_index=None,
                     kv_valid=None, use_kernel: bool = False):
@@ -347,6 +372,10 @@ def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
         if use_kernel and kv_x is None and causal:
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, k, v, causal=True, window=window)
+        elif (kv_x is None and causal and window is None
+              and fused_attention_applies(S, hd)):
+            from repro.kernels import ops as kops
+            out = kops.fused_attention(q, k, v)
         else:
             out = _online_attention(q, k, v, q_offset=0,
                                     causal=causal and kv_x is None,

@@ -5,7 +5,10 @@ chip would refuse: Mosaic lowerings interpret mode never checks, and
 programs that do not fit the chip's memory.  Kernels are compiled at the
 shapes their callers use; the stage programs at the full width of
 ``gwtf-llama-300m`` on the chunk the staged trainer dispatches there
-(one microbatch of 4 x 512 tokens).
+(one microbatch of 4 x 512 tokens).  The attention of the GPT and
+Nemotron stages is compiled through the fused kernel that a TPU's
+programs take: the tests steer the backend choice (``ops.on_tpu``),
+which sees the CPU here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers import
@@ -18,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.runtime.stages import (init_head_params, init_stage_params,
-                                       stage_kernels)
+                                       stage_kernels, stage_kinds)
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.ssd_scan import ssd_scan_bhsp
 
@@ -114,3 +118,41 @@ def test_head_loss_fits_one_chip(llama_stage, one_chip):
     labels = _spec(one_chip, (1,) + CHUNK, jnp.int32)
     compiled = k.head.lower(head, hidden, labels).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _fused_stage(one_chip, monkeypatch, name, stage):
+    """Stage ``stage`` of 4 of ``name`` at full width, with its attention
+    on the fused kernel: the compiled ``fwd_res`` and ``bwd_res`` and the
+    residual tree.  Kernels built afresh, so no trace of the CPU's choice
+    is reused."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_config(name)
+    k = stage_kernels.__wrapped__(cfg, True)
+    params = _on(one_chip, jax.eval_shape(
+        lambda key: init_stage_params(cfg, stage, 4, key),
+        jax.random.PRNGKey(0)))
+    kinds = stage_kinds(cfg, stage, 4)
+    x = _spec(one_chip, CHUNK + (cfg.d_model,), jnp.bfloat16)
+    fwd = k.fwd_res.lower(params, x, kinds).compile()
+    _, vjp = jax.eval_shape(k.fwd_res, params, x, kinds)
+    bwd = k.bwd_res.lower(_on(one_chip, vjp), x).compile()
+    return fwd, bwd, vjp
+
+
+def test_gpt_stage_keeps_no_attention_matrix(one_chip, monkeypatch):
+    """The GPT stage's residuals hold nothing of size S x S and total
+    under 800 MiB a microbatch (1,234 MiB on the XLA path)."""
+    fwd, bwd, vjp = _fused_stage(one_chip, monkeypatch, "gwtf-gpt-300m", 0)
+    assert "tpu_custom_call" in fwd.as_text()
+    assert "tpu_custom_call" in bwd.as_text()
+    leaves = jax.tree.leaves(vjp)
+    assert not [l.shape for l in leaves if l.shape[-2:] == (512, 512)]
+    assert sum(l.size * l.dtype.itemsize for l in leaves) < 800 * 2 ** 20
+
+
+def test_nemotron_attention_stage_fits_one_chip(one_chip, monkeypatch):
+    fwd, bwd, _ = _fused_stage(one_chip, monkeypatch,
+                               "nemotron3-nano-30b-a3b", 2)
+    assert "tpu_custom_call" in fwd.as_text()
+    assert _device_bytes(fwd) < HBM_BYTES
+    assert _device_bytes(bwd) < HBM_BYTES
