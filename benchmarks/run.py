@@ -17,15 +17,14 @@ def main() -> None:
                     help="paper-scale repetition counts (25 reps)")
     ap.add_argument("--only", default=None,
                     help="comma list: crash_llama,crash_gpt,node_addition,"
-                         "optimal,flow,convergence,roofline,ablation")
+                         "optimal,flow,convergence,ablation")
     args = ap.parse_args()
     reps = 25 if args.full else 3
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import (bench_ablation, bench_convergence,
                             bench_crash_gpt, bench_crash_llama, bench_flow,
-                            bench_node_addition, bench_optimal,
-                            bench_roofline)
+                            bench_node_addition, bench_optimal)
 
     suites = [
         ("crash_llama", lambda: bench_crash_llama.run(reps=reps)),
@@ -36,7 +35,6 @@ def main() -> None:
         ("flow", lambda: bench_flow.run(reps=max(3, reps))),
         ("convergence", lambda: bench_convergence.run(
             iterations=40 if args.full else 15)),
-        ("roofline", bench_roofline.run),
         ("ablation", lambda: bench_ablation.run(reps=max(4, reps // 2))),
     ]
 

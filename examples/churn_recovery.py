@@ -25,7 +25,7 @@ the detect–quarantine–reroute layer (these compare the GWTF engine
   the reputation layer, which quarantines the corrupt relay and plans
   around it (the simulator carries no real gradients, so this shows
   the detection/quarantine plumbing; the real gradient math lives in
-  the runtime trainer and `BENCH_exec.json`'s byzantine record).
+  the runtime trainer, tested in `tests/test_adversarial_faults.py`).
 
     PYTHONPATH=src python examples/churn_recovery.py               # all
     PYTHONPATH=src python examples/churn_recovery.py bernoulli

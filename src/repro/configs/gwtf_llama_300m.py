@@ -1,6 +1,6 @@
 """The paper's LLaMA-like evaluation model (Sec. VI: d_model=1024, 16
-layers; the paper lists n_heads=18 which does not divide 1024 — we use 16
-heads of dim 64 and note the adjustment in DESIGN.md)."""
+layers; the paper lists n_heads=18, which does not divide 1024, so this
+config uses 16 heads of dim 64)."""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(

@@ -31,9 +31,7 @@ training; the implementation lives in the layered
   convergence claim).
 
 The simulator answers *how long*, this runtime answers *what is
-learned*.  The pre-refactor per-microbatch whole-model-jit executor is
-frozen in :mod:`repro.core.runtime.reference` for benchmarking
-(``benchmarks/bench_exec.py``).
+learned*.
 """
 from __future__ import annotations
 

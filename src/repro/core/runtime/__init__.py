@@ -21,10 +21,7 @@ shape as :mod:`repro.core.sim`:
   layers, including requeue-instead-of-drop;
 * :mod:`repro.core.runtime.trainer` — gradient aggregation, AdamW
   updates, periodic per-stage checkpoints and joining-node bootstrap
-  via :func:`repro.checkpoint.store.restore_stage`;
-* :mod:`repro.core.runtime.reference` — the frozen pre-refactor
-  per-microbatch full-jit executor, kept for benchmarking
-  (``benchmarks/bench_exec.py``).
+  via :func:`repro.checkpoint.store.restore_stage`.
 
 ``repro.core.executor`` re-exports the drop-in trainer facades.
 """

@@ -152,8 +152,7 @@ def stage_forward(stage_params, x, cfg: ModelConfig, kinds=None):
     def body(carry, bp):
         h, _aux, _ = _apply_block(bp, carry, cfg, positions=positions,
                                   window=None, cache=None, write_index=None,
-                                  kv_valid=None, moe_impl="dense",
-                                  use_kernel=False)
+                                  kv_valid=None, moe_impl="dense")
         return h, None
 
     out, _ = jax.lax.scan(body, x, stage_params)

@@ -1,15 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
-
-These are the ground truth the kernels must match (tests sweep shapes and
-dtypes against them, interpret=True on CPU).
+"""Plain float32 attention, the oracle the attention paths are tested
+against (``tests/test_kernels.py``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-# SSD oracle: the sequential recurrence (also used by the model code)
-from repro.models.ssm import ssd_reference  # noqa: F401  (re-export)
 
 
 def attention_reference(q, k, v, *, causal: bool = True, window=None):
@@ -27,15 +22,3 @@ def attention_reference(q, k, v, *, causal: bool = True, window=None):
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)
-
-
-def ssd_scan_reference(x, dt, A, Bm, Cm):
-    """Kernel-layout wrapper around ssd_reference.
-
-    x: (B, H, S, P); dt: (B, H, S); A: (H,); Bm/Cm: (B, S, N)
-    -> (y (B, H, S, P), h_final (B, H, P, N))
-    """
-    xs = x.transpose(0, 2, 1, 3)           # (B, S, H, P)
-    dts = dt.transpose(0, 2, 1)            # (B, S, H)
-    y, hf = ssd_reference(xs, dts, A, Bm, Cm)
-    return y.transpose(0, 2, 1, 3).astype(x.dtype), hf

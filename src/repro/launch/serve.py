@@ -36,8 +36,6 @@ def main():
     ap.add_argument("--window", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="route prefill attention through the Pallas kernel")
     args = ap.parse_args()
 
     from repro.configs import get_config

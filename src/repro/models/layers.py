@@ -1,9 +1,10 @@
 """Core neural layers: norms, RoPE, MLP, chunked attention, embeddings.
 
 Everything is functional: ``init_*`` builds a param dict, the apply
-functions are pure.  Attention uses an online-softmax scan over KV blocks
-(the XLA-portable twin of the Pallas flash kernel in ``repro.kernels``),
-so 32k-context prefill never materialises an S x S score matrix.
+functions are pure.  Attention uses an online-softmax scan over KV blocks,
+so 32k-context prefill never materialises an S x S score matrix; on a
+TPU, training self-attention takes the fused flash kernel of
+``repro.kernels.ops`` instead (``fused_attention_applies``).
 """
 from __future__ import annotations
 
@@ -289,7 +290,7 @@ def fused_attention_applies(seq_len: int, head_dim: int) -> bool:
 
 def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
                     window=None, kv_x=None, cache=None, write_index=None,
-                    kv_valid=None, use_kernel: bool = False):
+                    kv_valid=None):
     """Self- or cross-attention with optional KV cache.
 
     x: (B, S, D).  kv_x: cross-attention memory (B, M, Dv) or None.
@@ -369,11 +370,8 @@ def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
         k = k.reshape(B, -1, KH, hd)
         v = v.reshape(B, -1, KH, hd)
         q, k, v = _constrain_attention_operands(q, k, v, H, KH)
-        if use_kernel and kv_x is None and causal:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=True, window=window)
-        elif (kv_x is None and causal and window is None
-              and fused_attention_applies(S, hd)):
+        if (kv_x is None and causal and window is None
+                and fused_attention_applies(S, hd)):
             from repro.kernels import ops as kops
             out = kops.fused_attention(q, k, v)
         else:

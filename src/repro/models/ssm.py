@@ -7,8 +7,8 @@ single-step decode path maintains (conv_state, ssm_state) caches for O(1)
 per-token decoding — this is what makes ``long_500k`` tractable for the
 ssm/hybrid archs.
 
-The pure-jnp math here doubles as the oracle for the Pallas ``ssd_scan``
-kernel (see repro/kernels/ref.py which re-exports ``ssd_reference``).
+``ssd_reference``, the sequential recurrence, is the oracle that
+``ssd_chunked`` is tested against.
 """
 from __future__ import annotations
 

@@ -168,7 +168,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
 # ---------------------------------------------------------------------------
 
 def _apply_block(bp, x, cfg: ModelConfig, *, positions, window, cache,
-                 write_index, kv_valid, moe_impl, use_kernel):
+                 write_index, kv_valid, moe_impl):
     """One decoder layer.  Returns (x, aux, new_cache)."""
     aux = jnp.float32(0.0)
     h = L.apply_norm(bp["ln1"], x, cfg)
@@ -183,7 +183,7 @@ def _apply_block(bp, x, cfg: ModelConfig, *, positions, window, cache,
     a_out, nc_a = L.apply_attention(
         bp["attn"], h, cfg, positions=positions, window=window,
         cache=cache.get("attn") if cache else None,
-        write_index=write_index, kv_valid=kv_valid, use_kernel=use_kernel)
+        write_index=write_index, kv_valid=kv_valid)
 
     if cfg.arch_type == "hybrid":
         s_out, nc_s = SSM.apply_mamba(bp["mamba"], h, cfg,
@@ -225,7 +225,7 @@ def _apply_cross_block(bp, x, vision, cfg: ModelConfig):
 def forward_hidden(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                    vision=None, window=None, cache=None, abs_index=None,
                    write_index=None, moe_impl: str = "dense",
-                   use_kernel: bool = False, remat: Optional[bool] = None):
+                   remat: Optional[bool] = None):
     """Run the decoder stack.  Returns (hidden, aux_loss, new_cache).
 
     abs_index:   absolute position of the first input token (decode).
@@ -253,8 +253,7 @@ def forward_hidden(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     do_remat = cfg.remat if remat is None else remat
     block = functools.partial(_apply_block, cfg=cfg, positions=positions,
                               window=window, write_index=write_index,
-                              kv_valid=kv_valid, moe_impl=moe_impl,
-                              use_kernel=use_kernel)
+                              kv_valid=kv_valid, moe_impl=moe_impl)
 
     aux0 = jnp.float32(0.0)
     if cfg.arch_type == "vlm" and cfg.cross_attn_every:
@@ -321,12 +320,11 @@ def forward_hidden(params, cfg: ModelConfig, *, tokens=None, embeds=None,
 # Entry points: train loss / prefill / decode
 # ---------------------------------------------------------------------------
 
-def train_loss(params, batch, cfg: ModelConfig, *, moe_impl="dense",
-               use_kernel=False):
+def train_loss(params, batch, cfg: ModelConfig, *, moe_impl="dense"):
     """batch: dict(tokens (B,S) | embeds (B,S,D), labels (B,S), [vision])."""
     hidden, aux, _ = forward_hidden(
         params, cfg, tokens=batch.get("tokens"), embeds=batch.get("embeds"),
-        vision=batch.get("vision"), moe_impl=moe_impl, use_kernel=use_kernel)
+        vision=batch.get("vision"), moe_impl=moe_impl)
     loss = L.chunked_xent_loss(params["embed"], hidden, batch["labels"], cfg)
     return loss + cfg.router_aux_coef * aux
 

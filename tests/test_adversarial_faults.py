@@ -383,6 +383,33 @@ class TestRuntimeGradientScreen:
             assert all(r.grads_flagged == 0 for r in rs)
         assert losses[False] == losses[True]
 
+    def test_screen_holds_end_loss_under_ceiling(self):
+        """Over six iterations with node 2 (1 of 6 relays) corrupting
+        every contribution it carries, the end-of-run loss of the
+        screened run stays within 0.25 of the clean run's, while the
+        unscreened run's moves further: the poison hurts, and the
+        screen contains it, detecting and quarantining node 2."""
+        def corrupt(net):
+            return CorruptGradientChurn([2], mode="perturb", scale=1.0,
+                                        seed=7, known_ids=net.nodes.keys())
+
+        def run(churn_model, grad_screen):
+            tr = _byz_trainer(churn_model=churn_model,
+                              grad_screen=grad_screen)
+            batches, quarantined = _batches(), False
+            for _ in range(6):
+                loss = float(tr.iteration(batches).loss)
+                quarantined = quarantined or tr.net.quarantined(2)
+            detections = sum(tr.timeline.counts(
+                kinds=("detection",), faults=("corrupt_gradient",)).values())
+            return loss, detections, quarantined
+
+        clean, _, _ = run(None, None)
+        undefended, _, _ = run(corrupt, False)
+        defended, detections, quarantined = run(corrupt, None)
+        assert abs(defended - clean) < 0.25 < abs(undefended - clean)
+        assert detections > 0 and quarantined
+
     def test_screen_off_lets_poison_through(self):
         tr = _byz_trainer(
             churn_model=lambda net: CorruptGradientChurn(

@@ -100,7 +100,8 @@ def test_other_attention_keeps_the_xla_path(monkeypatch, case):
 
 @pytest.mark.parametrize("seq_len,head_dim,takes", [
     (512, 64, True), (512, 128, True), (384, 64, True), (128, 32, True),
-    (512, 256, True), (200, 64, False), (64, 64, False), (512, 192, False)])
+    (512, 256, True), (1024, 64, True), (256, 128, True), (200, 64, False),
+    (64, 64, False), (512, 192, False), (320, 64, False)])
 def test_kernel_tiles_follow_the_shape(seq_len, head_dim, takes):
     bs = ops.fused_attention_blocks(seq_len, head_dim)
     assert (bs is not None) == takes
@@ -186,11 +187,13 @@ def _gaps(q, k, v, g, f, precision=None):
             for a, b in zip(got, want)]
 
 
-@pytest.mark.parametrize("S,H,KH,hd", [(256, 4, 4, 64), (384, 4, 2, 128)])
+@pytest.mark.parametrize("S,H,KH,hd", [(256, 4, 4, 64), (384, 4, 2, 128),
+                                      (256, 8, 2, 64)])
 def test_fused_attention_matches_online_in_interpret_mode(S, H, KH, hd):
     """The wrapper's layout, GQA repeat and scale, through the shipped
     kernel's forward and backward run by Pallas's TPU interpreter: one
-    tile of 256 and three of 128 (the online softmax across tiles)."""
+    tile of 256 and three of 128 (the online softmax across tiles), and
+    four query heads to a KV head."""
     q, k, v, g = _qkvg(1, S, H, KH, hd)
     with pltpu.force_tpu_interpret_mode():
         gaps = _gaps(q, k, v, g, ops.fused_attention)

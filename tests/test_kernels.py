@@ -1,119 +1,98 @@
-"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles.
+"""The attention and SSD paths the models run, against their plain
+float32 oracles over shapes and dtypes.
 
-Kernels run in interpret mode on CPU (the TPU lowering is the target;
-interpret executes the same kernel body in Python).
+``_online_attention`` is the attention of every program off the TPU and
+of every cache, window and cross-attention path on it; ``ssd_chunked``
+is Mamba-2's SSD everywhere.  The fused kernel that training attention
+takes on a TPU is checked in ``tests/test_fused_attention.py``.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.ref import attention_reference, ssd_scan_reference
-from repro.kernels.ssd_scan import ssd_scan_bhsp
+from repro.kernels.ref import attention_reference
+from repro.models import layers as L
+from repro.models.ssm import ssd_chunked, ssd_reference
 
 TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
 
 
 # ---------------------------------------------------------------------------
-# Flash attention
+# Online-softmax attention
 # ---------------------------------------------------------------------------
+
+def _reference_bshd(q, k, v, window=None):
+    """``attention_reference`` in the model's (B, S, H, hd) layout."""
+    B, S, H, D = q.shape
+    to = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+    out = attention_reference(to(q), to(k), to(v), causal=True,
+                              window=window)
+    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def _attention_gaps(q, k, v, window=None):
+    """Largest gap of ``_online_attention``'s output, dq, dk and dv, in
+    query blocks of 128, from the float32 reference's on the same
+    inputs, each relative to the reference's largest entry."""
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape, q.dtype)
+    online = functools.partial(L._online_attention, q_offset=0,
+                               causal=True, window=window, q_block=128)
+    out, vjp = jax.vjp(online, q, k, v)
+    got = (out,) + vjp(g)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v, g)]
+    out, vjp = jax.vjp(functools.partial(_reference_bshd, window=window),
+                       *f32[:3])
+    want = (out,) + vjp(f32[3])
+    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                  / jnp.max(jnp.abs(b))) for a, b in zip(got, want)]
+
+
+def _qkv(shape, dtype, seed):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(k, shape, dtype) for k in (k1, k2, k3))
+
 
 @pytest.mark.parametrize("S", [128, 256, 512])
 @pytest.mark.parametrize("D", [64, 128])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_shapes(S, D, dtype):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(S + D), 3)
-    BH = 2
-    q = jax.random.normal(k1, (BH, S, D), dtype)
-    k = jax.random.normal(k2, (BH, S, D), dtype)
-    v = jax.random.normal(k3, (BH, S, D), dtype)
-    out = flash_attention_bhsd(q, k, v, causal=True, block_q=64, block_k=64,
-                               interpret=True)
-    ref = attention_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **TOL[dtype])
+def test_online_attention_matches_reference(S, D, dtype):
+    """Causal self-attention, output and VJP, in one query block
+    (S = 128) and in several."""
+    gaps = _attention_gaps(*_qkv((1, S, 2, D), dtype, S + D))
+    assert max(gaps) < TOL[dtype]["rtol"], gaps
 
 
 @pytest.mark.parametrize("window", [32, 64, 128])
-def test_flash_attention_window(window):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(window), 3)
-    BH, S, D = 2, 256, 64
-    q = jax.random.normal(k1, (BH, S, D))
-    k = jax.random.normal(k2, (BH, S, D))
-    v = jax.random.normal(k3, (BH, S, D))
-    out = flash_attention_bhsd(q, k, v, causal=True, window=window,
-                               block_q=64, block_k=64, interpret=True)
-    ref = attention_reference(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("blocks", [(64, 128), (128, 64), (256, 256)])
-def test_flash_attention_block_shapes(blocks):
-    bq, bk = blocks
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-    BH, S, D = 1, 256, 64
-    q = jax.random.normal(k1, (BH, S, D))
-    k = jax.random.normal(k2, (BH, S, D))
-    v = jax.random.normal(k3, (BH, S, D))
-    out = flash_attention_bhsd(q, k, v, block_q=bq, block_k=bk,
-                               interpret=True)
-    ref = attention_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_flash_attention_gqa_wrapper():
-    key = jax.random.PRNGKey(0)
-    k1, k2, k3 = jax.random.split(key, 3)
-    B, S, H, KH, D = 2, 128, 8, 2, 64
-    q = jax.random.normal(k1, (B, S, H, D))
-    k = jax.random.normal(k2, (B, S, KH, D))
-    v = jax.random.normal(k3, (B, S, KH, D))
-    out = ops.flash_attention(q, k, v, block_q=64, block_k=64,
-                              interpret=True)
-    kr = jnp.repeat(k, H // KH, axis=2)
-    vr = jnp.repeat(v, H // KH, axis=2)
-    ref = attention_reference(
-        q.transpose(0, 2, 1, 3).reshape(B * H, S, D),
-        kr.transpose(0, 2, 1, 3).reshape(B * H, S, D),
-        vr.transpose(0, 2, 1, 3).reshape(B * H, S, D))
-    ref = ref.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_kernels_never_infer_interpret_mode():
-    """Interpret mode is never inferred: off the TPU, a kernel call
-    that does not ask for it raises instead of silently interpreting."""
-    x = jnp.ones((1, 128, 2, 64))
-    if jax.default_backend() == "tpu":
-        assert ops.flash_attention(x, x, x).shape == x.shape
-        return
-    with pytest.raises(ValueError, match="interpret"):
-        ops.flash_attention(x, x, x, block_q=64, block_k=64)
+def test_online_attention_window_matches_reference(window):
+    gaps = _attention_gaps(*_qkv((2, 256, 1, 64), jnp.float32, window),
+                           window=window)
+    assert max(gaps) < TOL[jnp.float32]["rtol"], gaps
 
 
 # ---------------------------------------------------------------------------
-# SSD scan
+# Chunked SSD
 # ---------------------------------------------------------------------------
+
+def _ssd_args(B, S, H, P, N, dtype, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (B, S, H, P), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (B, S, N), dtype)
+    Cm = jax.random.normal(ks[4], (B, S, N), dtype)
+    return x, dt, A, Bm, Cm
+
 
 @pytest.mark.parametrize("S,chunk", [(128, 32), (256, 64), (256, 128)])
 @pytest.mark.parametrize("N", [16, 64])
-def test_ssd_scan_shapes(S, chunk, N):
-    key = jax.random.PRNGKey(S + N)
-    ks = jax.random.split(key, 5)
-    B, H, P = 2, 3, 32
-    x = jax.random.normal(ks[0], (B, H, S, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, H, S)))
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
-    Bm = jax.random.normal(ks[3], (B, S, N))
-    Cm = jax.random.normal(ks[4], (B, S, N))
-    y, hf = ssd_scan_bhsp(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
-    yr, hfr = ssd_scan_reference(x, dt, A, Bm, Cm)
+def test_ssd_chunked_shapes(S, chunk, N):
+    args = _ssd_args(2, S, 3, 32, N, jnp.float32, S + N)
+    y, hf = ssd_chunked(*args, chunk=chunk)
+    yr, hfr = ssd_reference(*args)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(hfr),
@@ -121,17 +100,12 @@ def test_ssd_scan_shapes(S, chunk, N):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_scan_dtypes(dtype):
-    key = jax.random.PRNGKey(1)
-    ks = jax.random.split(key, 5)
-    B, H, S, P, N = 1, 2, 128, 16, 16
-    x = jax.random.normal(ks[0], (B, H, S, P), dtype)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, H, S))).astype(jnp.float32)
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
-    Bm = jax.random.normal(ks[3], (B, S, N), dtype)
-    Cm = jax.random.normal(ks[4], (B, S, N), dtype)
-    y, hf = ssd_scan_bhsp(x, dt, A, Bm, Cm, chunk=64, interpret=True)
-    yr, hfr = ssd_scan_reference(x, dt, A, Bm, Cm)
+def test_ssd_chunked_dtypes(dtype):
+    """Inputs in the model's dtypes against the recurrence on the same
+    values in float32."""
+    args = _ssd_args(1, 128, 2, 16, 16, dtype, 1)
+    y, hf = ssd_chunked(*args, chunk=64)
+    yr, hfr = ssd_reference(*(a.astype(jnp.float32) for a in args))
     tol = TOL[dtype]
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32),
@@ -155,7 +129,6 @@ def test_ssd_model_chunked_matches_sequential(chunk, h0):
     """The model's chunked SSD (all chunks at once) matches the sequential
     recurrence, from a zero or a carried-in state; chunk 192 is the
     single-chunk case, chunk 2 passes states across 96 chunks."""
-    from repro.models.ssm import ssd_chunked, ssd_reference
     x, dt, A, Bm, Cm, h_init = _ssd_inputs()
     h_init = None if h0 == "zeros" else h_init
     y_ref, h_ref = ssd_reference(x, dt, A, Bm, Cm, h0=h_init)
@@ -171,7 +144,6 @@ def test_ssd_model_chunked_gradients_match_sequential(chunk):
     """Gradients of the chunked SSD with respect to x, dt, A, B and C match
     those through the sequential recurrence, and stay finite for a head
     whose per-step decay exp(dt*A) underflows to zero."""
-    from repro.models.ssm import ssd_chunked, ssd_reference
     x, dt, A, Bm, Cm, h0 = _ssd_inputs()
     A = A.at[0].set(-200.0)
     gy = jax.random.normal(jax.random.PRNGKey(7), x.shape)

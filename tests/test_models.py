@@ -142,31 +142,3 @@ class TestConfigReduction:
         if r.num_heads:
             assert r.num_heads % max(r.num_kv_heads, 1) == 0
             assert r.num_heads * r.head_dim <= 8 * r.d_model
-
-
-class TestKernelIntegration:
-    """The use_kernel=True path routes model attention through the Pallas
-    flash kernel — must match the jnp path."""
-
-    def test_forward_with_kernel_matches(self, monkeypatch):
-        import functools
-
-        import numpy as np
-        from repro.kernels import ops
-        from repro.models.transformer import forward_hidden, init_params
-        if jax.default_backend() != "tpu":
-            # the model never asks for interpret mode; off the TPU the
-            # test asks for it on the model's behalf
-            monkeypatch.setattr(ops, "flash_attention", functools.partial(
-                ops.flash_attention, interpret=True))
-        cfg = get_config("tinyllama-1.1b").reduced(num_layers=2,
-                                                   d_model=128)
-        key = jax.random.PRNGKey(0)
-        params = init_params(cfg, key)
-        toks = jax.random.randint(key, (1, 128), 0, cfg.vocab_size)
-        h_ref, _, _ = forward_hidden(params, cfg, tokens=toks,
-                                     use_kernel=False)
-        h_ker, _, _ = forward_hidden(params, cfg, tokens=toks,
-                                     use_kernel=True)
-        np.testing.assert_allclose(np.asarray(h_ker), np.asarray(h_ref),
-                                   rtol=2e-3, atol=2e-3)

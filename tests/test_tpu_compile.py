@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with jaxlib, so it refuses here what the
 chip would refuse: Mosaic lowerings interpret mode never checks, and
-programs that do not fit the chip's memory.  Kernels are compiled at the
-shapes their callers use; the stage programs at the full width of
+programs that do not fit the chip's memory.  The fused attention kernel
+is compiled, forward and backward, at the head shapes of the models
+that take it; the stage programs at the full width of
 ``gwtf-llama-300m`` on the chunk the staged trainer dispatches there
 (one microbatch of 4 x 512 tokens).  The attention of the GPT and
 Nemotron stages is compiled through the fused kernel that a TPU's
@@ -23,8 +24,6 @@ from repro.configs import get_config
 from repro.core.runtime.stages import (init_head_params, init_stage_params,
                                        stage_kernels, stage_kinds)
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.ssd_scan import ssd_scan_bhsp
 
 HBM_BYTES = 16e9          # one v5e chip
 CHUNK = (4, 512)          # microbatch x sequence, paper Sec. VI
@@ -64,23 +63,24 @@ def _device_bytes(compiled) -> float:
             + m.temp_size_in_bytes)
 
 
-def test_flash_attention_compiles(one_chip):
-    q = _spec(one_chip, (64, 512, 64), jnp.bfloat16)
-    compiled = jax.jit(flash_attention_bhsd).lower(q, q, q).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+@pytest.mark.parametrize("H,KH,hd", [(16, 16, 64), (8, 2, 128)],
+                         ids=["gpt", "gqa128"])
+def test_fused_attention_vjp_compiles(one_chip, H, KH, hd):
+    """``jax.vjp`` of the fused kernel on one 4 x 512 chunk: GPT's 16
+    heads of 64, and 128-wide heads with four query heads to a KV head.
+    Both the forward and the backward program are Mosaic kernels."""
+    q = _spec(one_chip, CHUNK + (H, hd), jnp.bfloat16)
+    kv = _spec(one_chip, CHUNK + (KH, hd), jnp.bfloat16)
 
+    def fwd(q, k, v):
+        return jax.vjp(ops.fused_attention, q, k, v)
 
-def test_ssd_scan_compiles(one_chip):
-    cfg = get_config("mamba2-130m")
-    B, H, S = 1, cfg.ssm_heads, 2048
-    P, N = cfg.ssm_head_dim, cfg.ssm_state
-    args = (_spec(one_chip, (B, H, S, P), jnp.bfloat16),
-            _spec(one_chip, (B, H, S), jnp.float32),
-            _spec(one_chip, (H,), jnp.float32),
-            _spec(one_chip, (B, S, N), jnp.bfloat16),
-            _spec(one_chip, (B, S, N), jnp.bfloat16))
-    compiled = jax.jit(ssd_scan_bhsp).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    out, vjp = jax.eval_shape(fwd, q, kv, kv)
+    fwd_text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    bwd = jax.jit(lambda vjp, g: vjp(g)).lower(
+        _on(one_chip, vjp), _on(one_chip, out)).compile()
+    assert "tpu_custom_call" in fwd_text
+    assert "tpu_custom_call" in bwd.as_text()
 
 
 @pytest.fixture(scope="module")

@@ -307,7 +307,53 @@ def make_mbs(cfg, seed=0):
     return DataNodeShard(dc, 0, 1).microbatches()
 
 
+# Forced wire codecs on a seeded churn-free run of 16 microbatches over
+# two stages: the end-of-run loss delta from the fp32 wire stays under a
+# ceiling that catches a broken encode/decode pair, not quantisation
+# drift, and the boundary bytes shrink at least by the codec's floor.
+WIRE_LOSS_DELTA_MAX = {"bf16": 0.05, "int8": 0.5, "top-k": 2.5}
+WIRE_BYTES_REDUCTION_MIN = {"bf16": 1.9, "int8": 3.0, "top-k": 6.0}
+WIRE_D_MODEL, WIRE_SEQ, WIRE_MICROBATCHES, WIRE_ITERATIONS = 128, 64, 16, 4
+
+
+def _wire_run(codec):
+    """(last loss, last iteration's boundary bytes, its completed
+    microbatches) of ``WIRE_ITERATIONS`` iterations with ``codec`` forced
+    on the wire."""
+    cfg = dataclasses.replace(
+        get_config("gwtf-llama-300m").reduced(num_layers=2,
+                                              d_model=WIRE_D_MODEL),
+        vocab_size=512)
+    net = geo_distributed_network(
+        num_stages=2, relay_capacities=[16] * 6, num_data_nodes=1,
+        data_capacity=WIRE_MICROBATCHES, rng=np.random.default_rng(0))
+    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=WIRE_SEQ,
+                    batch_size=WIRE_MICROBATCHES, microbatch_size=1, seed=0)
+    mbs = DataNodeShard(dc, 0, 1).microbatches()
+    t = RuntimeTrainer(cfg, net, lr=1e-3, seed=0, churn_model=TraceChurn([]),
+                       wire_codec=codec)
+    for _ in range(WIRE_ITERATIONS):
+        r = t.iteration({0: mbs})
+    return float(r.loss), t.last_wire_bytes, r.completed
+
+
+@pytest.fixture(scope="module")
+def fp32_wire_run():
+    return _wire_run("fp32")
+
+
 class TestRuntimeWire:
+    @pytest.mark.parametrize("codec", ["bf16", "int8", "top-k"])
+    def test_forced_wire_codec_bounded_loss_delta_and_bytes(
+            self, fp32_wire_run, codec):
+        fp_loss, fp_bytes, completed = fp32_wire_run
+        loss, wire_bytes, _ = _wire_run(codec)
+        assert fp_bytes == 0
+        # every completed microbatch crosses the one boundary as fp32 rows
+        raw = completed * WIRE_SEQ * WIRE_D_MODEL * 4
+        assert raw / wire_bytes >= WIRE_BYTES_REDUCTION_MIN[codec]
+        assert abs(loss - fp_loss) <= WIRE_LOSS_DELTA_MAX[codec]
+
     def test_forced_bf16_wire_bytes_and_bounded_loss_delta(self):
         cfg = tiny_cfg()
         mbs = make_mbs(cfg)
