@@ -135,7 +135,9 @@ def _chunk_pass(stages: StageCompute, store: ActivationStore,
     (not a no-op wire) for fp32 so the bit-identity path stays
     untouched.  Accumulates per-stage gradients into ``grad_stage`` in
     place; returns ``(loss_sum, g_head)`` with the embedding share
-    included.
+    included.  The loss sum is dispatched right after the head and read
+    (the chunk's one blocking read) once the backward is queued, so the
+    read never waits for the backward.
     """
     S = len(stage_params)
     x = stages.embed(head_params, toks)
@@ -156,6 +158,11 @@ def _chunk_pass(stages: StageCompute, store: ActivationStore,
     h = x.reshape(B, per, seq, D)
     with TraceAnnotation("gwtf.head"):
         losses, g_head, g_hidden = stages.head_loss(head_params, h, labels)
+        # queued ahead of the backward, so the blocking read below
+        # waits for this chunk's forward and head only and the device
+        # still has the backward to run while the host dispatches on
+        loss_dev = jnp.sum(losses)
+        loss_dev.copy_to_host_async()
     g = g_hidden.reshape(B * per, seq, D)
     for s in reversed(range(S)):
         if replay is not None:
@@ -176,7 +183,7 @@ def _chunk_pass(stages: StageCompute, store: ActivationStore,
         store.drop(s, ids)
     g_emb = stages.embed_backward(head_params, toks, g)
     with TraceAnnotation("gwtf.loss_sync"):
-        loss_sum = float(jnp.sum(losses))
+        loss_sum = float(loss_dev)
     with TraceAnnotation("gwtf.accumulate"):
         g_head = jax.tree.map(jnp.add, g_head, g_emb)
     return loss_sum, g_head

@@ -316,3 +316,108 @@ def test_dispatch_chunk_override_keeps_trainers_bit_identical():
     assert r.loss == cen.iteration(mbs)
     assert rt.stages.fwd_calls == [2, 2]      # 4 mbs / chunks of 2
     assert cen.stages.fwd_calls == [2, 2]
+
+
+# ---------------------------------------------------------------------------
+# Loss read order inside a chunk
+# ---------------------------------------------------------------------------
+
+class _RecordingStages:
+    """`StageCompute` proxy that logs each dispatch by method name and
+    keeps the per-microbatch losses every ``head_loss`` returned."""
+
+    def __init__(self, inner, log):
+        self._inner, self._log = inner, log
+        self.head_losses = []
+
+    def __getattr__(self, name):
+        attr = getattr(self._inner, name)
+        if not callable(attr):
+            return attr
+
+        def call(*args, **kwargs):
+            self._log.append(("stage", name))
+            out = attr(*args, **kwargs)
+            if name == "head_loss":
+                self.head_losses.append(out[0])
+            return out
+        return call
+
+
+class _RecordingJnp:
+    """``jax.numpy`` as the trainer module sees it, logging ``sum``."""
+
+    def __init__(self, log):
+        self._log = log
+
+    def __getattr__(self, name):
+        return getattr(jnp, name)
+
+    def sum(self, *args, **kwargs):
+        self._log.append(("jnp", "sum"))
+        return jnp.sum(*args, **kwargs)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["fused", "remat"])
+def test_chunk_loss_reduced_after_head_and_read_after_backward(
+        monkeypatch, remat):
+    """Per chunk, the loss sum is dispatched right after the head and
+    before the first backward, and the one blocking read
+    (``gwtf.loss_sync``) comes after the last backward and the
+    embedding pull-back; the value read equals ``float(jnp.sum(losses))``
+    of the head's losses bit for bit."""
+    from repro.core.runtime import trainer as trainer_mod
+
+    log = []
+
+    class _RecordingSpan:
+        def __init__(self, name, **kwargs):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("span", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    returned = []
+    chunk_pass = trainer_mod._chunk_pass
+
+    def recording_chunk_pass(*args, **kwargs):
+        out = chunk_pass(*args, **kwargs)
+        returned.append(out[0])
+        return out
+
+    monkeypatch.setattr(trainer_mod, "TraceAnnotation", _RecordingSpan)
+    monkeypatch.setattr(trainer_mod, "jnp", _RecordingJnp(log))
+    monkeypatch.setattr(trainer_mod, "_chunk_pass", recording_chunk_pass)
+
+    cfg = tiny_cfg()
+    mbs = make_mbs(cfg)                       # 4 microbatches
+    dn = make_net().data_nodes()[0].id
+    rt = RuntimeTrainer(cfg, make_net(), lr=3e-3, seed=0, remat=remat,
+                        churn_model=TraceChurn([]), dispatch_chunk=2)
+    stages = _RecordingStages(rt.stages, log)
+    rt.stages = stages
+    r = rt.iteration({dn: mbs})
+
+    backward = "backward" if remat else "backward_from_residuals"
+    starts = [i for i, e in enumerate(log) if e == ("span", "gwtf.chunk")]
+    assert len(starts) == 2 == r.host_syncs
+    for lo, hi in zip(starts, starts[1:] + [len(log)]):
+        chunk = log[lo:hi]
+        head = chunk.index(("stage", "head_loss"))
+        sums = [i for i, e in enumerate(chunk) if e == ("jnp", "sum")]
+        bwds = [i for i, e in enumerate(chunk) if e == ("stage", backward)]
+        embed_bwd = chunk.index(("stage", "embed_backward"))
+        syncs = [i for i, e in enumerate(chunk)
+                 if e == ("span", "gwtf.loss_sync")]
+        assert len(sums) == 1 and len(syncs) == 1 and len(bwds) == 2
+        assert head < sums[0] < bwds[0]
+        assert syncs[0] > max(bwds[-1], embed_bwd)
+
+    assert len(returned) == len(stages.head_losses) == 2
+    for got, losses in zip(returned, stages.head_losses):
+        assert got == float(jnp.sum(losses))
+    assert r.loss == sum(returned) / len(mbs)
